@@ -57,7 +57,7 @@ from typing import Any, Dict, List, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
+from jax.sharding import PartitionSpec as P
 
 from ..dtypes import ArrayT, Float, SparseT, TupleT
 from ..hwimg import Val
@@ -94,6 +94,9 @@ def _as_input(raw):
     return np.asarray(raw)
 
 
+# the mesh axis a sharded serving batch splits its frames over
+FRAME_AXIS = "frames"
+
 _FMA_PROBE: Dict[str, bool] = {}
 
 
@@ -112,7 +115,7 @@ def backend_contracts_fma() -> bool:
     if key not in _FMA_PROBE:
         x = np.float32(1.0 + 2.0 ** -12)
         p = np.float32(x * x)
-        with enable_x64():
+        with jax.enable_x64(True):
             r = jax.jit(lambda a, b: a * a - b)(jnp.float32(x),
                                                 jnp.float32(p))
         _FMA_PROBE[key] = bool(np.asarray(r) != np.float32(0.0))
@@ -196,15 +199,24 @@ class _Task:
             env[n.uid] = _eval_node(n, env)
         return tuple(env[u] for u in self.out_uids)
 
-    def call(self, mode: str, invals, in_axes, donate: bool = False):
+    def call(self, mode: str, invals, in_axes, donate: bool = False,
+             mesh=None):
         if mode == "batch" and not any(a == 0 for a in in_axes):
             mode = "frame"              # constant subgraph: no frame axis
         donate_idx = self.dead_in if (donate and mode == "batch") else ()
         key = (mode, donate_idx) if mode == "frame" \
-            else ("batch", in_axes, donate_idx)
+            else ("batch", in_axes, donate_idx, mesh)
         if key not in self._jit:
             fn = self._fn if mode == "frame" else jax.vmap(self._fn,
                                                            in_axes=in_axes)
+            if mode == "batch" and mesh is not None:
+                # one shard of the frame axis per device: a Pallas kernel
+                # is not partitioned by XLA, so every device runs the
+                # vmapped program on its local frames
+                fn = jax.shard_map(
+                    fn, mesh=mesh, in_specs=tuple(
+                        P(FRAME_AXIS) if a == 0 else P() for a in in_axes),
+                    out_specs=P(FRAME_AXIS), check_vma=False)
             self._jit[key] = jax.jit(fn, donate_argnums=donate_idx)
         return self._jit[key](*invals)
 
@@ -428,7 +440,8 @@ class CompiledPipeline:
             else:
                 env[n.uid] = _as_input(raw)
 
-    def _run(self, inputs: Dict[str, Any], mode: str, donate: bool = False):
+    def _run(self, inputs: Dict[str, Any], mode: str, donate: bool = False,
+             mesh=None):
         env: Dict[int, Any] = {}
         self._load_inputs(inputs, env)
         # batch mode: inputs carry the frame axis; a vmapped task broadcasts
@@ -440,7 +453,7 @@ class CompiledPipeline:
             axes = tuple(0 if batched.get(u, False) else None
                          for u in t.in_uids)
             outs = t.call(mode, [env[u] for u in t.in_uids], axes,
-                          donate=donate)
+                          donate=donate, mesh=mesh)
             env.update(zip(t.out_uids, outs))
             vmapped = mode == "batch" and any(a == 0 for a in axes)
             for u in t.out_uids:
@@ -461,7 +474,7 @@ class CompiledPipeline:
         self.signatures[sig] = self.signatures.get(sig, 0) + 1
 
     def __call__(self, inputs: Dict[str, Any]):
-        with enable_x64():
+        with jax.enable_x64(True):
             if self.debug:
                 return _to_numpy(self._eval(inputs))
             self._record(inputs, "frame")
@@ -470,13 +483,14 @@ class CompiledPipeline:
     def run_batch(self, inputs: Dict[str, Any]):
         """vmap over a leading frame axis on every input (the throughput /
         serving entry point), through the same jit program cache."""
-        with enable_x64():
+        with jax.enable_x64(True):
             if self.debug:
                 return _to_numpy(jax.vmap(self._eval)(inputs))
             self._record(inputs, "batch")
             return _to_numpy(self._run(inputs, "batch"))
 
-    def run_batch_device(self, inputs: Dict[str, Any], donate: bool = False):
+    def run_batch_device(self, inputs: Dict[str, Any], donate: bool = False,
+                         mesh=None):
         """The serving call path: batched execution that keeps results on
         device (jax arrays, asynchronously dispatched — no host sync), so a
         caller can overlap host→device transfer of the next batch with this
@@ -484,10 +498,12 @@ class CompiledPipeline:
         additionally donates each segment's dead input buffers to XLA
         (frame buffers are single-use in a server, so their pages can be
         reused for outputs; a no-op where the platform lacks donation).
-        Callers must not reuse donated input arrays afterwards."""
-        with enable_x64():
+        Callers must not reuse donated input arrays afterwards.  ``mesh``
+        (a 1-d mesh over the ``frames`` axis, serve/sharding.py) runs every
+        program once per device on that device's shard of the frames."""
+        with jax.enable_x64(True):
             self._record(inputs, "serve")
-            return self._run(inputs, "batch", donate=donate)
+            return self._run(inputs, "batch", donate=donate, mesh=mesh)
 
     @staticmethod
     def frame_signature(inputs: Dict[str, Any]) -> Tuple:
@@ -501,7 +517,7 @@ class CompiledPipeline:
         """Eager per-node evaluation returning every live node's value
         keyed by uid — the node-level diffing hook (debug tooling)."""
         vals: Dict[int, Any] = {}
-        with enable_x64():
+        with jax.enable_x64(True):
             env: Dict[int, Any] = {}
             self._load_inputs(inputs, env)
             for n in self.ir.order:

@@ -69,14 +69,28 @@ class LoweringIR:
                     i.uid for i in v.inputs),
                 ty=v.ty, shape=type_shape(v.ty), scalar=scalar_of(v.ty),
                 input_tys=tuple(i.ty for i in v.inputs))
+        self.out = out
         self.root: int = out.uid
         self._next_uid = max(self.nodes) + 1
+        self._ranges: Optional[Dict[int, Tuple[int, int]]] = None
         self.order: List[IRNode] = []
         self.refresh()
 
     # ---- queries ----
     def node(self, uid: int) -> IRNode:
         return self.nodes[uid]
+
+    def value_ranges(self) -> Dict[int, Tuple[int, int]]:
+        """Proven value hull ``(lo, hi)`` of every integer node whose range
+        the static analysis (analysis/ranges.py) bounds, computed once.  A
+        rewrite keeps the value of the uid it rewrites, so the hulls hold
+        for rewritten nodes too."""
+        if self._ranges is None:
+            from ...analysis.ranges import analyze
+            self._ranges = {u: (r.lo, r.hi)
+                            for u, r in analyze(self.out).nodes.items()
+                            if r.lo is not None}
+        return self._ranges
 
     def effective_inputs(self, n: IRNode) -> Tuple[int, ...]:
         """Scheduling inputs: a dispatched node depends only on its fused

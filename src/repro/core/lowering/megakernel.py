@@ -4,64 +4,80 @@ The engine's generic path executes one XLA op per IR node, materializing
 every intermediate image in memory.  Hardware doesn't work that way — the
 paper's pipelines stream rows through line buffers and FIFOs — and neither
 does this emitter: for an eligible segment it generates a *single* Pallas
-kernel whose grid walks the output frame in row blocks.  Input frames are
-VMEM-resident; every interior node keeps only the rowful *window* its
-consumers demand (its line buffer), sized statically by propagating row
-demands backward through the segment's stencil/pad/crop/resampling
-geometry; the point-op/stencil/reduce chain is applied in registers block
-by block, so no intermediate image is ever written back whole.
+kernel whose grid walks the output frame in row blocks.  Segment inputs
+arrive as row strips (a BlockSpec per strip, the conv2d kernel's halo
+scheme); every interior node keeps only the rowful *window* its consumers
+demand (its line buffer); the point-op/stencil/reduce chain is applied
+block by block, so no intermediate image is ever written back whole.
 
-Row-demand propagation.  Each node's window is ``rows [off(r0), off+size)``
-of its virtual frame, where ``r0`` is the block's first output row and
-``off`` composes the segment's geometry: stencils shift by their window
-base and widen by the window height, pad/crop shift, down/upsampling
-scale by the stride (including floor division — resampling pyramids
-reconverge with *skewed* row phases, the same skew the FIFO solver sees).
-Window sizes must be static, so every ``off`` carries a rational slope and
-offset-interval bound (``slope*r0 + [lo, hi]``).  Reconvergent demands on
-one producer merge by taking the traced row minimum and bounding the
-union's length from the intervals — only possible when slopes agree;
-otherwise the producer falls back to whole-frame evaluation inside the
-kernel (sound: still one kernel, just not line-buffered at that node).
+Row-demand propagation.  At grid step ``i`` each node's window is rows
+``[a*i + c, a*i + c + size)`` of its virtual frame: an affine offset with
+integer rate ``a`` (rows per grid step) and static ``c`` and ``size``.
+Demands propagate backward through the segment's geometry: stencils shift
+by their window base and widen by the window height, pad/crop shift,
+downsampling scales the rate, upsampling divides it (exactly — the block
+is chosen so every division is whole, which keeps resampling pyramids'
+skewed row phases static).  Reconvergent demands on one producer must
+share a rate and merge into the union window.  Because every rate and
+offset is static, a consumer finds its rows in its producer's window at a
+static position: the kernel body has no data-dependent indexing at all,
+only the strips' BlockSpec index maps move with ``i``.
+
+The kernel's value layout is *planar*: a node of type ``T[w, h]`` whose
+elements are themselves arrays (stencil patches, stacked channels) is held
+as a numpy object array, indexed by the element's trailing dims, of 2-D
+``(rows, w)`` planes.  Every in-kernel operation is then a 2-D one in a
+32-bit dtype, the form a TPU vector unit computes in: integers ride int32
+wherever the node's declared width, or the value range that
+analysis/ranges.py proves for it, fits (the engine's int64 carrier is
+converted at the kernel boundary), floats ride f32.  A segment that would
+need a wider carrier raises ``MKUnsupported`` and keeps the generic path.
+
 Virtual rows outside a node's frame read as zero (the executor's stencil
-zero-fill), maintained by masking each window after compute.
+zero-fill): segment inputs are zero-padded around the frame, and an
+interior node that feeds a stencil is masked on the rows of its window
+that leave its frame.
 
 Verification contract (two tiers, see engine.py): integer segments are
-bit-exact — each node's result is wrapped by ``jnp_mask`` exactly like the
-generic path.  Float segments are promised within ``FLOAT_ULP_BOUND``
-ULPs of the reference executor; the emitter currently does better
-(bit-exact on CPU) by computing f32 multiplies in f64 and rounding once —
-the product of two f32 values is exactly representable in f64, so the
-rounded result IS the IEEE f32 multiply, and the intervening converts
-deny XLA the f32 mul→add pattern that FMA contraction rewrites.  That is
-what lets the engine drop the FMA segment split for fused f32 segments:
-inside a megakernel we control the FLOP order.
+bit-exact — each node's result is wrapped to its declared width exactly
+like the generic path, and int32 arithmetic wraps like the hardware does.
+Float segments are promised within ``FLOAT_ULP_BOUND`` ULPs of the
+reference executor.  Inside the kernel every f32 multiply is an IEEE
+multiply that no backend may contract into a following add
+(``_exact_f32_mul``), which is what lets the engine drop the FMA segment
+split for fused f32 segments; the ULP bound is there for divide and
+square root, which an accelerator may round differently.
 """
 from __future__ import annotations
 
 import math
 from dataclasses import dataclass
-from fractions import Fraction
-from typing import Any, Callable, Dict, List, Tuple
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
-from ...kernels.stream import (MK_BLOCK_ROWS, interpret_default,
-                               mask_outside_frame, nbytes, row_block_spec,
-                               take_rows, whole_spec, window_rows)
-from ..dtypes import Bits, Float, Int, TupleT, UInt
+from ...kernels.stream import (LANES, MK_BLOCK_ROWS, SUBLANES,
+                               decimate_lanes, interpret_default, nbytes,
+                               pad_lanes, pick_rows, shift_lanes)
+from ..dtypes import Bits, BoolT, Float, Int, TupleT, UInt, mask_to_width
 from ..hwimg import map_reshape_plans, scalar_of, type_shape
 from .ir import IRNode, LoweringIR
-from .lowerers import _JNP_FNS, LOWERERS, jnp_mask, jnp_point_fn
+from .lowerers import _JNP_FNS, jnp_point_fn
 
 # the float tier of the verification contract: megakernel outputs are
-# within this many ULPs of the reference executor.  Tests and the bench
-# gate enforce it; the current CPU emission is bit-exact (see module
-# docstring), the bound is headroom for backends whose FMA behavior we
-# don't control (real-TPU lane, ROADMAP).
+# within this many ULPs of the reference executor.  Tests and the chip
+# smoke check enforce it.
 FLOAT_ULP_BOUND = 4
+
+# a node whose element type fans out into more planes than this (e.g. a
+# wide stencil of a wide stencil) would unroll into an unreasonably large
+# kernel; such segments keep the generic path
+MAX_PLANES = 512
+
+_INT32 = (-(1 << 31), (1 << 31) - 1)
 
 
 class MKUnsupported(Exception):
@@ -85,7 +101,7 @@ _COMPUTE_OPS = frozenset({
 
 # float-touching point-functions with known-safe behavior inside a fused
 # kernel: the _JNP_FNS lowerings (FloatMul rides the contraction-proof
-# f64 route, add/sub/div/sqrt can't start an FMA pattern once every
+# multiply, add/sub/div/sqrt can't start an FMA pattern once every
 # multiply is protected) plus int->float converts and compares.  An
 # unknown user PointFn touching float could hide an f32 mul->add
 # composition, so it stays on the generic path, where the engine's FMA
@@ -100,17 +116,6 @@ def _is_float(s) -> bool:
 def _elems(ty) -> List:
     """Image leaves of a node type (tuple fan points carry several)."""
     return list(ty.elems) if isinstance(ty, TupleT) else [ty]
-
-
-def _has_rows(ty) -> bool:
-    return all(len(type_shape(t)) >= 2 for t in _elems(ty))
-
-
-def _carrier_dtype(ty):
-    s = scalar_of(ty)
-    if isinstance(s, (UInt, Bits, Int)):
-        return jnp.int64                # the engine's integer carrier
-    return jnp.dtype(s.np_dtype())
 
 
 def streamable(n: IRNode) -> bool:
@@ -136,9 +141,11 @@ def streamable(n: IRNode) -> bool:
     if n.op == "Downsample":
         # executor semantics stride-slice (ceil) while the typed shape
         # floors; they agree only when the strides divide the frame — the
-        # generic path keeps the odd-size case
+        # generic path keeps the odd-size case.  Lanes decimate within one
+        # 128-lane register, so the lane stride must divide it.
         shape = type_shape(n.input_tys[0])
-        if shape[0] % n.params["sy"] or shape[1] % n.params["sx"]:
+        if (shape[0] % n.params["sy"] or shape[1] % n.params["sx"]
+                or LANES % n.params["sx"]):
             return False
     return True
 
@@ -150,66 +157,115 @@ def worth_emitting(nodes: List[IRNode]) -> bool:
 
 
 # --------------------------------------------------------------------------
-# contraction-safe point functions (the float tier's implementation)
+# carriers and point functions (the in-kernel value semantics)
+
+def _carrier_dtype(ty):
+    """The engine's carrier outside kernels (int64 for every integer)."""
+    s = scalar_of(ty)
+    if isinstance(s, (UInt, Bits, Int)):
+        return jnp.int64
+    return jnp.dtype(s.np_dtype())
+
+
+def _declared_range(s) -> Optional[Tuple[int, int]]:
+    if isinstance(s, (UInt, Bits)):
+        return 0, (1 << s.bits()) - 1
+    if isinstance(s, Int):
+        return -(1 << (s.bits() - 1)), (1 << (s.bits() - 1)) - 1
+    if isinstance(s, BoolT):
+        return 0, 1
+    return None
+
+
+def _fits32(lo: int, hi: int) -> bool:
+    return _INT32[0] <= lo and hi <= _INT32[1]
+
+
+def _kernel_dtype(s):
+    """In-kernel carrier of a scalar type (int range checked separately)."""
+    if _is_float(s):
+        if jnp.dtype(s.np_dtype()) != jnp.float32:
+            raise MKUnsupported(f"no 32-bit kernel carrier for {s!r}")
+        return jnp.float32
+    if isinstance(s, BoolT):
+        return jnp.bool_
+    return jnp.int32
+
+
+def _io_dtype(dt):
+    """Kernel operand/result dtype: bool crosses the boundary as int32."""
+    return jnp.int32 if dt == jnp.bool_ else dt
+
+
+# one quiet-NaN bit pattern: the fence below flips its payload bit
+_FENCE_NAN = 0x7FC00001
+
 
 def _exact_f32_mul(a, b):
-    # f32 x f32 is exact in f64; rounding the f64 product to f32 precision
-    # IS the IEEE f32 multiply.  The round must be reduce_precision (bit
-    # ops), not a convert: LLVM narrows fptrunc(fmul(fpext, fpext)) back
-    # to an f32 fmul and then contracts it with a neighboring fadd into an
-    # FMA — the exact drift this detour exists to prevent.  (Products in
-    # the f32 subnormal range can still double-round; the ULP tier's bound
-    # absorbs that corner.)
-    a32 = jnp.asarray(a).astype(jnp.float32)
-    b32 = jnp.asarray(b).astype(jnp.float32)
-    w = a32.astype(jnp.float64) * b32.astype(jnp.float64)
-    return jax.lax.reduce_precision(w, 8, 23).astype(jnp.float32)
+    # the IEEE f32 product, fenced off from a consuming add: the product's
+    # bits pass through an integer select that differs from the identity
+    # only on one NaN payload, so neither XLA nor LLVM can fold it away,
+    # and no backend can contract the multiply with the add behind it
+    # into an FMA — the drift that turns FLOW's det == 0 into a tiny
+    # nonzero residual
+    bits = jax.lax.bitcast_convert_type(a * b, jnp.int32)
+    bits = jnp.where(bits == _FENCE_NAN, bits ^ 2, bits)
+    return jax.lax.bitcast_convert_type(bits, jnp.float32)
+
+
+_MK_FNS: Dict[str, Callable[[Dict[str, Any]], Callable]] = {
+    "FloatMul": lambda p: _exact_f32_mul,
+    "AbsDiff": lambda p: (lambda a, b: jnp.abs(a - b)),
+}
 
 
 def mk_point_fn(fn) -> Callable:
-    if fn.name == "FloatMul":
-        return _exact_f32_mul
+    if fn.name in _MK_FNS:
+        return _MK_FNS[fn.name](dict(fn.params))
     return jnp_point_fn(fn)
 
 
-def _fold(fn, flat):
-    acc = flat[..., 0]
-    for i in range(1, flat.shape[-1]):
-        acc = fn(acc, flat[..., i])
-    return acc
+def _mask_k(x, s):
+    """jnp_mask on the 32-bit kernel carrier: wrap to the declared width.
+    Widths of 32 bits and more are identities here — the carrier check
+    admitted such a node only with its values proven inside int32."""
+    if isinstance(s, (UInt, Bits)) and s.bits() < 32:
+        return x & ((1 << s.bits()) - 1)
+    if isinstance(s, Int) and s.bits() < 32:
+        sh = 32 - s.bits()
+        return (x << sh) >> sh
+    return x
 
 
-def _mk_lower_map(v: IRNode, p, ins):
-    fn = mk_point_fn(p["fn"])
-    args = [jnp.asarray(a) if plan is None else jnp.asarray(a).reshape(plan)
-            for a, plan in zip(ins, map_reshape_plans(v.ty, v.input_tys))]
-    return fn(*args)
+# --------------------------------------------------------------------------
+# planar values: object arrays of 2-D planes
+
+def _obj(shape) -> np.ndarray:
+    return np.empty(shape, dtype=object)
 
 
-def _mk_lower_reduce(v, p, ins):
-    x = ins[0]
-    return _fold(mk_point_fn(p["fn"]), x.reshape(x.shape[:-2] + (-1,)))
+def _wrap(x) -> np.ndarray:
+    """numpy returns 0-d object results as bare objects; re-box them."""
+    if isinstance(x, np.ndarray) and x.dtype == object:
+        return x
+    a = _obj(())
+    a[()] = x
+    return a
 
 
-def _mk_lower_reduce_patch(v, p, ins):
-    x = ins[0]
-    h_, w_, sh_, sw_ = x.shape[:4]
-    flat = x.reshape((h_, w_, sh_ * sw_) + x.shape[4:])
-    fn = mk_point_fn(p["fn"])
-    acc = flat[:, :, 0]
-    for i in range(1, sh_ * sw_):
-        acc = fn(acc, flat[:, :, i])
-    return acc
+def _pmap(f, *xs) -> np.ndarray:
+    """Apply ``f`` leafwise with numpy broadcasting over the planes."""
+    return _wrap(np.frompyfunc(f, len(xs), 1)(*xs))
 
 
-# whole-frame fallback nodes reuse the generic table, with the
-# contraction-safe point functions swapped in
-_MK_LOWERERS = dict(LOWERERS)
-_MK_LOWERERS.update({
-    "Map": _mk_lower_map,
-    "Reduce": _mk_lower_reduce,
-    "ReducePatch": _mk_lower_reduce_patch,
-})
+def _tmap(f, v):
+    """``_pmap`` through a tuple-typed value."""
+    return tuple(_pmap(f, e) for e in v) if isinstance(v, tuple) \
+        else _pmap(f, v)
+
+
+def _n_planes(ty) -> int:
+    return sum(math.prod(type_shape(t)[2:]) for t in _elems(ty))
 
 
 # --------------------------------------------------------------------------
@@ -217,66 +273,44 @@ _MK_LOWERERS.update({
 
 @dataclass(frozen=True)
 class Demand:
-    """Window ``rows [off(r0), off+size)`` of a node's virtual frame, with
-    ``off`` bounded by ``slope*r0 + [lo, hi]`` (exact rationals; ``r0`` is
-    the block's first output row)."""
+    """Window rows ``[a*i + c, a*i + c + size)`` of a node's virtual frame
+    at grid step ``i``."""
 
-    off: Callable[[Any], Any]
+    a: int
+    c: int
     size: int
-    slope: Fraction
-    lo: Fraction
-    hi: Fraction
+
+    def stays_inside(self, grid: int, h: int) -> bool:
+        """Does the window stay within rows [0, h) at every grid step?"""
+        return self.c >= 0 and self.a * (grid - 1) + self.c + self.size <= h
 
 
-WHOLE = "whole"                         # whole-frame fallback marker
+class _RowRate(MKUnsupported):
+    """An upsampling stride doesn't divide the block's row rate."""
 
 
-def _seed(block_rows: int) -> Demand:
-    return Demand(lambda r0: r0, block_rows, Fraction(1), Fraction(0),
-                  Fraction(0))
-
-
-def _shift(d: Demand, c: int, grow: int = 0) -> Demand:
-    if c == 0 and grow == 0:
-        return d
-    f = d.off
-    return Demand(lambda r0: f(r0) + c, d.size + grow, d.slope,
-                  d.lo + c, d.hi + c)
+def _shift(d: Demand, k: int, grow: int = 0) -> Demand:
+    return Demand(d.a, d.c + k, d.size + grow)
 
 
 def _scale(d: Demand, sy: int) -> Demand:
-    f = d.off
-    return Demand(lambda r0: f(r0) * sy, sy * (d.size - 1) + 1,
-                  d.slope * sy, d.lo * sy, d.hi * sy)
+    return Demand(d.a * sy, d.c * sy, sy * (d.size - 1) + 1)
 
 
 def _floordiv(d: Demand, sy: int) -> Demand:
-    f = d.off
-    return Demand(lambda r0: f(r0) // sy, (d.size + sy - 2) // sy + 1,
-                  d.slope / sy, (d.lo - (sy - 1)) / sy, d.hi / sy)
+    if d.a % sy:
+        raise _RowRate(f"row rate {d.a} is not a multiple of stride {sy}")
+    lo, hi = d.c // sy, (d.c + d.size - 1) // sy
+    return Demand(d.a // sy, lo, hi - lo + 1)
 
 
-def _row_min(a, b):
-    # static block starts (grid == 1) keep offsets as Python ints, which
-    # downstream turns into slice/pad instead of gather/select
-    if isinstance(a, int) and isinstance(b, int):
-        return min(a, b)
-    return jnp.minimum(a, b)
-
-
-def _merge(a, b):
-    """Union of two demands on one producer.  Needs equal slopes so the
-    slope term cancels and the union's length stays statically bounded;
-    otherwise the producer falls back to whole-frame evaluation."""
-    if a is None:
-        return b
-    if WHOLE in (a, b) or a.slope != b.slope:
-        return WHOLE
-    fa, fb = a.off, b.off
-    lo = min(a.lo, b.lo)
-    size = int(math.ceil(max(a.hi + a.size, b.hi + b.size) - lo))
-    return Demand(lambda r0: _row_min(fa(r0), fb(r0)), size,
-                  a.slope, lo, min(a.hi, b.hi))
+def _merge(x: Optional[Demand], y: Demand) -> Demand:
+    if x is None:
+        return y
+    if x.a != y.a:
+        raise MKUnsupported(f"reconvergent row rates {x.a} and {y.a}")
+    c = min(x.c, y.c)
+    return Demand(x.a, c, max(x.c + x.size, y.c + y.size) - c)
 
 
 def _map_streams_input(n: IRNode, j: int) -> bool:
@@ -286,11 +320,12 @@ def _map_streams_input(n: IRNode, j: int) -> bool:
     return len(s_in) >= 2 and s_in[:2] == type_shape(n.ty)[:2]
 
 
-def _input_demands(n: IRNode, d: Demand) -> List[Any]:
-    """Per-input row demand implied by demand ``d`` on node ``n``."""
+def _input_demands(n: IRNode, d: Demand) -> List[Optional[Demand]]:
+    """Per-input row demand implied by demand ``d`` on node ``n`` (None:
+    a broadcast operand, read whole)."""
     p = n.params
     if n.op == "Map":
-        return [d if _map_streams_input(n, j) else WHOLE
+        return [d if _map_streams_input(n, j) else None
                 for j in range(len(n.inputs))]
     if n.op in ("Reduce", "ReducePatch", "ArgMin", "Replicate", "Stack",
                 "Concat", "FanOut", "FanIn", "TupleIndex"):
@@ -309,6 +344,50 @@ def _input_demands(n: IRNode, d: Demand) -> List[Any]:
     raise MKUnsupported(f"no demand rule for {n.op}")
 
 
+def _demand_pass(nodes: List[IRNode], out_uids, consts,
+                 block: int) -> Dict[int, Demand]:
+    """Reverse pass: the row window of every streamed node and of every
+    segment input."""
+    demand: Dict[int, Demand] = {u: Demand(block, 0, block)
+                                 for u in out_uids}
+    for n in reversed(nodes):
+        if n.uid in consts:
+            continue
+        for u, di in zip(n.inputs, _input_demands(n, demand[n.uid])):
+            if (di is None) != (u in consts):
+                raise MKUnsupported(
+                    f"%{n.uid}:{n.op} operand %{u} is neither a streamed "
+                    f"image nor a constant")
+            if di is not None:
+                demand[u] = _merge(demand.get(u), di)
+    return demand
+
+
+def _plan_rows(nodes, out_uids, in_uids, consts, h_out: int,
+               block_rows: Optional[int]) -> Tuple[int, Dict[int, Demand]]:
+    """Pick the row block and run the demand pass.  The default block is
+    the smallest multiple of MK_BLOCK_ROWS for which every upsampling
+    stride divides its row rate and every input strip is whole 32-bit
+    register rows; an explicit ``block_rows`` is taken as given."""
+    cands = ([block_rows] if block_rows is not None
+             else [MK_BLOCK_ROWS * m for m in (1, 2, 4, 8)])
+    why = "no candidate block"
+    for block in cands:
+        block = min(block, h_out)
+        try:
+            demand = _demand_pass(nodes, out_uids, consts, block)
+        except _RowRate as exc:
+            why = str(exc)
+            continue
+        if block_rows is None and block < h_out and any(
+                demand[u].a % SUBLANES for u in in_uids):
+            why = f"input strips of {block}-row blocks are not whole " \
+                  f"{SUBLANES}-row tiles"
+            continue
+        return block, demand
+    raise MKUnsupported(f"no row block fits: {why}")
+
+
 # --------------------------------------------------------------------------
 # emission
 
@@ -323,9 +402,8 @@ class Megakernel:
     block_rows: int
     grid: int
     linebuf_bytes: int                  # windowed (line-buffered) bytes
-    whole_bytes: int                    # whole-frame fallback bytes
     float_nodes: int                    # nodes under the ULP tier
-    n_winsum: int = 0                   # box-sum chains -> reduce_window
+    n_winsum: int = 0                   # box-sum chains summed separably
     note: str = ""
     flops: int = 0                      # scalar ops per frame (int ops too)
     io_bytes: int = 0                   # kernel-boundary bytes per frame
@@ -340,85 +418,76 @@ class Megakernel:
     def report_line(self) -> str:
         tier = (f"float tier (ULP<={FLOAT_ULP_BOUND})" if self.float_nodes
                 else "integer tier (bit-exact)")
-        extra = f" (+{self.whole_bytes}B whole)" if self.whole_bytes else ""
-        ws = (f", {self.n_winsum} box-sum chain(s) via reduce_window"
+        ws = (f", {self.n_winsum} box-sum chain(s) as separable sums"
               if self.n_winsum else "")
         return (f"{self.name}: {self.n_nodes} fused nodes, "
                 f"grid={self.grid}x{self.block_rows}rows, "
-                f"linebuf={self.linebuf_bytes}B{extra}, {tier}{ws}")
+                f"linebuf={self.linebuf_bytes}B, {tier}{ws}")
 
 
-def _demand_pass(nodes: List[IRNode], span, out_uids,
-                 block: int) -> Dict[int, Any]:
-    """Reverse pass: row demands (window offsets + static sizes)."""
-    demand: Dict[int, Any] = {u: _seed(block) for u in out_uids}
-    for n in reversed(nodes):
-        d = demand.get(n.uid)
-        if d is None:       # pragma: no cover - every span exit is an out
-            raise MKUnsupported(f"%{n.uid} has no consumer demand")
-        if n.op == "Const" or not _has_rows(n.ty):
-            d = demand[n.uid] = WHOLE   # consts/scalars evaluate whole
-        if d is WHOLE:
-            for u in n.inputs:
-                if u in span:
-                    demand[u] = WHOLE
-            continue
-        for u, di in zip(n.inputs, _input_demands(n, d)):
-            if u in span:
-                demand[u] = _merge(demand.get(u), di)
-    return demand
+def _check_carriers(ir: LoweringIR, nodes: List[IRNode],
+                    in_uids) -> Dict[int, List[Any]]:
+    """Per-node, per-leaf kernel dtype; raises MKUnsupported where an
+    integer value (or an intermediate of a non-wrapping op) can leave
+    int32 — those segments need the engine's int64 carrier."""
+    ranges = ir.value_ranges()
+
+    def rng(u: int, s) -> Tuple[int, int]:
+        lo, hi = _declared_range(s)
+        if not isinstance(ir.nodes[u].ty, TupleT) and u in ranges:
+            lo, hi = max(lo, ranges[u][0]), min(hi, ranges[u][1])
+        return lo, hi
+
+    dts: Dict[int, List[Any]] = {}
+    for u in list(in_uids) + [n.uid for n in nodes]:
+        n = ir.nodes[u]
+        leaves = []
+        for t in _elems(n.ty):
+            s = scalar_of(t)
+            dt = _kernel_dtype(s)
+            if dt == jnp.int32 and not _fits32(*rng(u, s)):
+                raise MKUnsupported(
+                    f"%{u}:{n.op} values {rng(u, s)} need the int64 carrier")
+            leaves.append(dt)
+        dts[u] = leaves
+        if n.op == "Map" and n.params["fn"].name in ("AbsDiff", "Abs") \
+                and u not in in_uids:
+            ins = [rng(v, scalar_of(ir.nodes[v].ty)) for v in n.inputs]
+            if len(ins) == 2:
+                bound = max(abs(ins[0][1] - ins[1][0]),
+                            abs(ins[1][1] - ins[0][0]))
+            else:
+                bound = max(abs(ins[0][0]), abs(ins[0][1]))
+            if bound > _INT32[1]:
+                raise MKUnsupported(f"%{u}:{n.op} can overflow int32")
+    return dts
 
 
-def emit_megakernel(ir: LoweringIR, nodes: List[IRNode],
-                    in_uids: Tuple[int, ...], out_uids: Tuple[int, ...],
-                    name: str = "mk",
-                    block_rows: int | None = None) -> Megakernel:
-    """Build the fused row-streaming Pallas kernel for one segment.
+def _const_planes(n: IRNode, dt) -> np.ndarray:
+    """A Const as planes of numpy scalars of its kernel dtype (baked into
+    the kernel as literals: no operand, no captured array)."""
+    s = scalar_of(n.ty)
+    v = np.asarray(n.params["value"])
+    if _declared_range(s) is not None and not isinstance(s, BoolT):
+        v = mask_to_width(v, s)
+    v = v.astype(dt)
+    out = _obj(v.shape)
+    for idx in np.ndindex(v.shape):
+        out[idx] = v[idx]
+    return out
 
-    ``nodes`` is the segment in schedule order; ``in_uids`` are values
-    produced outside it (whole frames at call time), ``out_uids`` the
-    values it must materialize.  Raises MKUnsupported when the segment's
-    geometry defeats static window sizing (the engine then keeps the
-    generic XLA path).
 
-    ``block_rows`` picks the streaming granularity.  Default: in real
-    (TPU) mode MK_BLOCK_ROWS, so frames stream through VMEM line buffers;
-    in interpret mode the whole frame is one block — a 1-step grid makes
-    every row offset a static Python int, so window extraction lowers to
-    slices and pads XLA can fuse (the dynamic-offset gather path costs
-    ~10x warm latency under the CPU interpreter)."""
-    for n in nodes:
-        if not streamable(n):
-            raise MKUnsupported(f"%{n.uid}:{n.op} is not streamable")
-    span = {n.uid for n in nodes}
-    out_nodes = [ir.nodes[u] for u in out_uids]
+def _winsum_chains(ir: LoweringIR, nodes: List[IRNode], span,
+                   out_uids) -> Tuple[Dict[int, IRNode], set]:
+    """Peephole: integer box-sum chains -> separable sums.
 
-    heights = set()
-    for o in out_nodes:
-        for ty in _elems(o.ty):
-            shape = type_shape(ty)
-            if len(shape) < 2:
-                raise MKUnsupported(f"output %{o.uid} is not an image")
-            heights.add(shape[0])
-    if len(heights) != 1:
-        raise MKUnsupported(f"outputs disagree on height: {heights}")
-    h_out = heights.pop()
-    interpret = interpret_default()
-    if block_rows is None:
-        block_rows = h_out if interpret else MK_BLOCK_ROWS
-    block = min(block_rows, h_out)
-    grid = -(-h_out // block)
-
-    demand = _demand_pass(nodes, span, out_uids, block)
-
-    # ---- peephole: integer box-sum chains -> one window reduce ----
-    # Stencil -> (Map(AddMSBs))* -> Reduce(Add|AddAsync), single-consumer
-    # all the way, integer-carried, plain 2-D frames.  Integer addition on
-    # the int64 carrier is associative (AddMSBs only widens), so summing
-    # the patch via lax.reduce_window is bit-exact while replacing sh*sw
-    # slice taps per window with one op — the in-kernel mirror of the
-    # window_sum rewrite rule that megakernel emission subsumes (FLOW's
-    # five 8x8 second-moment sums are the poster child).
+    Stencil -> (Map(AddMSBs))* -> Reduce(Add|AddAsync), single-consumer
+    all the way, integer-carried, plain 2-D frames.  Integer addition wraps
+    consistently in the 32-bit carrier (AddMSBs only widens), so summing
+    the patch as rows-then-lanes is bit-exact while replacing sh*sw taps
+    with sh + sw adds — the in-kernel mirror of the window_sum rewrite
+    rule that megakernel emission subsumes (FLOW's five 8x8
+    second-moment sums are the poster child)."""
     out_set = set(out_uids)
     winsum: Dict[int, IRNode] = {}      # Reduce uid -> its Stencil node
     skip: set = set()                   # chain interiors: never computed
@@ -442,45 +511,84 @@ def emit_megakernel(ir: LoweringIR, nodes: List[IRNode],
         if tail is not None:
             winsum[tail.uid] = n
             skip.update(x.uid for x in chain)
+    return winsum, skip
 
-    # ---- byte accounting (the line-buffer report) ----
-    # Always accounted at the streaming block size: it answers "how much
-    # VMEM do the line buffers need when this kernel streams", regardless
-    # of the whole-frame block the interpreter runs with.
-    stream_block = min(MK_BLOCK_ROWS, h_out)
-    acct = (demand if block == stream_block
-            else _demand_pass(nodes, span, out_uids, stream_block))
-    linebuf = whole_b = 0
-    float_nodes = 0
+
+def emit_megakernel(ir: LoweringIR, nodes: List[IRNode],
+                    in_uids: Tuple[int, ...], out_uids: Tuple[int, ...],
+                    name: str = "mk",
+                    block_rows: int | None = None) -> Megakernel:
+    """Build the fused row-streaming Pallas kernel for one segment.
+
+    ``nodes`` is the segment in schedule order; ``in_uids`` are values
+    produced outside it (whole frames at call time), ``out_uids`` the
+    values it must materialize.  Raises MKUnsupported when the segment's
+    geometry defeats static windows or its values need a carrier wider
+    than 32 bits (the engine then keeps the generic XLA path).
+
+    ``block_rows`` overrides the streaming granularity (rows of output per
+    grid step); by default it is chosen by ``_plan_rows``."""
     for n in nodes:
-        if any(_is_float(scalar_of(t)) for t in _elems(n.ty)):
-            float_nodes += 1
-        if n.uid in skip:
-            continue                    # box-sum interiors never materialize
-        d = acct[n.uid]
-        for ty in _elems(n.ty):
+        if not streamable(n):
+            raise MKUnsupported(f"%{n.uid}:{n.op} is not streamable")
+    span = {n.uid for n in nodes}
+    out_nodes = [ir.nodes[u] for u in out_uids]
+    consts = set()
+    for n in nodes:
+        if n.op == "Const":
+            consts.add(n.uid)
+        elif n.inputs and all(u in consts for u in n.inputs):
+            raise MKUnsupported(f"%{n.uid}:{n.op} is a constant expression")
+    heights = set()
+    for o in out_nodes:
+        if o.uid in consts:
+            raise MKUnsupported(f"output %{o.uid} is a constant")
+        for ty in _elems(o.ty):
             shape = type_shape(ty)
-            if d is WHOLE or len(shape) < 2:
-                whole_b += nbytes(shape, _carrier_dtype(ty))
-            else:
-                linebuf += nbytes((d.size,) + tuple(shape[1:]),
-                                  _carrier_dtype(ty))
+            if len(shape) < 2:
+                raise MKUnsupported(f"output %{o.uid} is not an image")
+            heights.add(shape[0])
+    if len(heights) != 1:
+        raise MKUnsupported(f"outputs disagree on height: {heights}")
+    h_out = heights.pop()
+    for n in nodes:
+        if _n_planes(n.ty) > MAX_PLANES:
+            raise MKUnsupported(f"%{n.uid}:{n.op} unrolls into "
+                                f"{_n_planes(n.ty)} planes")
 
-    # ---- roofline accounting (per frame) ----
+    block, demand = _plan_rows(nodes, out_uids, in_uids, consts, h_out,
+                               block_rows)
+    grid = -(-h_out // block)
+    dts = _check_carriers(ir, nodes, in_uids)
+    winsum, skip = _winsum_chains(ir, nodes, span, out_uids)
+    const_vals = {n.uid: _const_planes(n, dts[n.uid][0])
+                  for n in nodes if n.uid in consts}
+    # interior values a stencil reads must be zero outside their frame
+    zero_fill = {n.uid for n in nodes if n.uid not in consts
+                 and any(ir.nodes[c].op == "Stencil" for c in n.consumers
+                         if c in span)
+                 and not demand[n.uid].stays_inside(
+                     grid, type_shape(_elems(n.ty)[0])[0])}
+
+    # ---- accounting: line buffers, roofline (per frame) ----
     # flops counts scalar arithmetic ops (integer ops included at weight
     # 1): Map = one op per output scalar, Reduce/ReducePatch = one op per
     # input scalar (the add/cmp tree), fused box-sum chains = window size
-    # per output scalar; geometry ops (Stencil/Pad/Crop/resample) move
-    # data, 0 ops.  io_bytes is traffic across the kernel boundary —
-    # operand frames in, materialized outputs out — i.e. what must cross
-    # HBM<->VMEM when the segment streams.
+    # per output scalar; geometry ops move data, 0 ops.  io_bytes is
+    # traffic across the kernel boundary at the kernel's 32-bit carriers.
     def _scalars(ty) -> int:
         return sum(math.prod(type_shape(t)) for t in _elems(ty))
 
-    flops = 0
+    linebuf = flops = float_nodes = 0
     for n in nodes:
-        if n.uid in skip:
+        if any(_is_float(scalar_of(t)) for t in _elems(n.ty)):
+            float_nodes += 1
+        if n.uid in consts or n.uid in skip:
             continue
+        for t in _elems(n.ty):
+            shape = type_shape(t)
+            linebuf += nbytes((demand[n.uid].size,) + tuple(shape[1:]),
+                              jnp.int32)
         if n.uid in winsum:
             _l, _b, sh, sw = _winsum_geometry(winsum[n.uid])
             flops += sh * sw * _scalars(n.ty)
@@ -488,134 +596,149 @@ def emit_megakernel(ir: LoweringIR, nodes: List[IRNode],
             flops += _scalars(n.ty)
         elif n.op in ("Reduce", "ReducePatch"):
             flops += _scalars(n.input_tys[0])
+    io_bytes = sum(nbytes(type_shape(t), jnp.int32)
+                   for u in list(in_uids) + list(out_uids)
+                   for t in _elems(ir.nodes[u].ty))
 
-    # ---- output layout: one pallas output per image leaf ----
-    out_layout = []                     # (uid, elem_idx|None, shape, dtype)
+    # ---- operand layout: every input leaf plane as row strips ----
+    strips = {}                         # uid -> (k0, n_strips, top rows)
+    for u in in_uids:
+        d = demand[u]
+        k0, k1 = d.c // d.a, (d.c + d.size - 1) // d.a
+        strips[u] = (k0, k1 - k0 + 1, max(0, -k0) * d.a)
+    in_layout = []                      # (uid, leaf, plane index, width)
+    for u in in_uids:
+        for k, t in enumerate(_elems(ir.nodes[u].ty)):
+            shape = type_shape(t)
+            for idx in np.ndindex(shape[2:]):
+                in_layout.append((u, k, idx, shape[1]))
+    out_layout = []                     # (uid, leaf, plane index, width)
     for o in out_nodes:
-        elems = _elems(o.ty)
-        for k, ty in enumerate(elems):
-            out_layout.append((o.uid, k if len(elems) > 1 else None,
-                               type_shape(ty), _carrier_dtype(ty)))
+        for k, t in enumerate(_elems(o.ty)):
+            shape = type_shape(t)
+            for idx in np.ndindex(shape[2:]):
+                out_layout.append((o.uid, k, idx, shape[1]))
+    node_list = [n for n in nodes if n.uid not in consts
+                 and n.uid not in skip]
 
-    # Const nodes can't evaluate inside the kernel (pallas rejects captured
-    # array constants) — they become extra whole-frame operands instead
-    const_list = [(n.uid, n.params["value"], n.ty)
-                  for n in nodes if n.op == "Const"]
-    node_list = [n for n in nodes if n.op != "Const"]
-    in_list = list(in_uids)
+    def kernel(*refs):
+        i = pl.program_id(0)
+        refs = iter(refs)
+        val: Dict[int, Any] = dict(const_vals)
+        planes: Dict[Tuple[int, int], np.ndarray] = {}
+        for u, k, idx, _w in in_layout:
+            d, (k0, ns, _top) = demand[u], strips[u]
+            win = jnp.concatenate([next(refs)[...] for _ in range(ns)],
+                                  axis=0) if ns > 1 else next(refs)[...]
+            lo = d.c - k0 * d.a
+            win = win[lo:lo + d.size]
+            if dts[u][k] == jnp.bool_:
+                win = win != 0
+            if (u, k) not in planes:
+                shape = type_shape(_elems(ir.nodes[u].ty)[k])
+                planes[(u, k)] = _obj(shape[2:])
+            planes[(u, k)][idx] = win
+        for u in in_uids:
+            legs = tuple(planes[(u, k)]
+                         for k in range(len(_elems(ir.nodes[u].ty))))
+            val[u] = legs if isinstance(ir.nodes[u].ty, TupleT) else legs[0]
+        masks: Dict[Tuple, Any] = {}
 
-    io_bytes = (
-        sum(nbytes(type_shape(t), _carrier_dtype(t))
-            for u in in_list for t in _elems(ir.nodes[u].ty))
-        + sum(nbytes(type_shape(t), _carrier_dtype(t))
-              for _u, _v, t in const_list)
-        + sum(nbytes(s, dt) for _u, _k, s, dt in out_layout))
-    leaf_is_tuple = {u: isinstance(ir.nodes[u].ty, TupleT) for u in in_list}
+        def rows(u: int, c: int, size: int):
+            """Rows [c, c+size) (window coordinates: a*i + c) of u."""
+            rel = c - demand[u].c
+            assert 0 <= rel and rel + size <= demand[u].size, (u, c, size)
+            return _tmap(lambda x: x[rel:rel + size], val[u])
+
+        def inside(d: Demand, shape, lo: int, hi: int):
+            """Bool (size, w): window rows whose virtual row is in [lo, hi)."""
+            key = (d, shape, lo, hi)
+            if key not in masks:
+                r = (jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+                     + (i * d.a + d.c))
+                masks[key] = (r >= lo) & (r < hi)
+            return masks[key]
+
+        for n in node_list:
+            d = demand[n.uid]
+            if n.uid in winsum:
+                raw = _window_winsum(winsum[n.uid], d, rows)
+            else:
+                raw = _window_node(n, d, rows, val, inside, grid)
+            legs = list(raw) if isinstance(raw, tuple) else [raw]
+            legs = [_pmap(lambda x, s=scalar_of(t): _mask_k(x, s), leg)
+                    for leg, t in zip(legs, _elems(n.ty))]
+            if n.uid in zero_fill:
+                h_n = type_shape(_elems(n.ty)[0])[0]
+                legs = [_pmap(lambda x: jnp.where(
+                    inside(d, x.shape, 0, h_n), x,
+                    jnp.zeros((), x.dtype)), leg) for leg in legs]
+            val[n.uid] = tuple(legs) if isinstance(n.ty, TupleT) \
+                else legs[0]
+
+        # write output rows [a*i, a*i + block)
+        for (u, k, idx, _w), ref in zip(out_layout, refs):
+            v = val[u]
+            x = (v[k] if isinstance(v, tuple) else v)[idx]
+            rel = -demand[u].c
+            x = x[rel:rel + block]
+            ref[...] = x.astype(jnp.int32) if x.dtype == jnp.bool_ else x
 
     def apply(*leaf_vals):
-        flat, leaf_slots = [], []
-        for val in leaf_vals:
-            parts = list(val) if isinstance(val, tuple) else [val]
-            leaf_slots.append(len(parts))
-            flat.extend(jnp.asarray(x) for x in parts)
-        n_leaf = len(flat)
-        const_scalar = []               # 0-d consts ride as (1, 1) operands
-        for _uid, value, ty in const_list:
-            cv = jnp_mask(jnp.asarray(value), ty)
-            const_scalar.append(cv.ndim == 0)
-            flat.append(cv.reshape(1, 1) if cv.ndim == 0 else cv)
+        operands, in_specs = [], []
+        for u, val in zip(in_uids, leaf_vals):
+            d, (k0, ns, top) = demand[u], strips[u]
+            legs = list(val) if isinstance(val, tuple) else [val]
+            for k, leg in enumerate(legs):
+                x = jnp.asarray(leg).astype(_io_dtype(dts[u][k]))
+                h = x.shape[0]
+                rows_total = (grid + k0 + ns - 1 + top // d.a) * d.a
+                x = jnp.pad(x, ((top, max(0, rows_total - top - h)),)
+                            + ((0, 0),) * (x.ndim - 1))[:rows_total]
+                for idx in np.ndindex(x.shape[2:]):
+                    plane = x[(slice(None), slice(None)) + idx]
+                    for s in range(ns):
+                        off = k0 + s + top // d.a
+                        operands.append(plane)
+                        in_specs.append(pl.BlockSpec(
+                            (d.a, plane.shape[1]),
+                            lambda g, _o=off: (g + _o, 0)))
+        out_shape = [jax.ShapeDtypeStruct((grid * block, w),
+                                          _io_dtype(dts[u][k]))
+                     for u, k, _idx, w in out_layout]
+        out_specs = [pl.BlockSpec((block, w), lambda g: (g, 0))
+                     for _u, _k, _idx, w in out_layout]
+        # the kernel is 32-bit throughout: trace it with x64 off, so index
+        # arithmetic inside jnp helpers (gathers, repeats) stays int32
+        with jax.enable_x64(False):
+            outs = pl.pallas_call(
+                kernel, grid=(grid,), in_specs=in_specs,
+                out_specs=out_specs, out_shape=out_shape,
+                interpret=interpret_default(), name=name)(*operands)
 
-        def kernel(*refs):
-            in_refs, out_refs = refs[:len(flat)], refs[len(flat):]
-            # static start for a 1-step grid: offsets stay Python ints
-            # and window extraction lowers to slices, not gathers
-            r0 = 0 if grid == 1 else pl.program_id(0) * block
-            whole: Dict[int, Any] = {}             # uid -> whole value
-            win: Dict[int, Tuple[Any, Any]] = {}   # uid -> (window, off)
-            it = iter(in_refs[:n_leaf])
-            for u, k in zip(in_list, leaf_slots):
-                vals = tuple(next(it)[...] for _ in range(k))
-                whole[u] = vals if leaf_is_tuple[u] or k > 1 else vals[0]
-            for (u, _v, _t), ref, was_0d in zip(const_list,
-                                                in_refs[n_leaf:],
-                                                const_scalar):
-                whole[u] = ref[0, 0] if was_0d else ref[...]
-
-            def rows(u, off, size):
-                """Rows [off, off+size) of node u's virtual frame."""
-                if u in win:
-                    v, base = win[u]
-                    if isinstance(v, tuple):
-                        return tuple(window_rows(e, off - base, size)
-                                     for e in v)
-                    return window_rows(v, off - base, size)
-                v = whole[u]
-                if isinstance(v, tuple):
-                    return tuple(take_rows(e, off, size) for e in v)
-                return take_rows(v, off, size)
-
-            for n in node_list:
-                if n.uid in skip:
-                    continue            # folded into a winsum reduce
-                d = demand[n.uid]
-                if d is WHOLE:
-                    if n.uid in winsum:
-                        stn = winsum[n.uid]
-                        raw = _winsum_whole(stn, whole[stn.inputs[0]])
-                    else:
-                        ins = [whole[u] for u in n.inputs]
-                        raw = _MK_LOWERERS[n.op](n, n.params, ins)
-                    whole[n.uid] = jnp_mask(raw, n.ty)
-                    continue
-                off = d.off(r0)
-                if n.uid in winsum:
-                    raw = _winsum_window(winsum[n.uid], off, d.size, rows)
-                else:
-                    raw = _window_node(n, d, off, rows, whole)
-                val = jnp_mask(raw, n.ty)
-                h_n = type_shape(_elems(n.ty)[0])[0]
-                if isinstance(val, tuple):
-                    val = tuple(mask_outside_frame(e, off, h_n)
-                                for e in val)
-                else:
-                    val = mask_outside_frame(val, off, h_n)
-                win[n.uid] = (val, off)
-
-            # write output rows [r0, r0 + block)
-            for ref, (uid, k, _shape, _dt) in zip(out_refs, out_layout):
-                if uid in win:
-                    v, base = win[uid]
-                    e = v[k] if k is not None else v
-                    ref[...] = window_rows(e, r0 - base, block)
-                else:                   # whole-fallback output
-                    v = whole[uid]
-                    e = v[k] if k is not None else v
-                    ref[...] = take_rows(e, r0, block)
-
-        in_specs = [whole_spec(tuple(x.shape)) for x in flat]
-        out_specs = [row_block_spec(block, s) for _, _, s, _ in out_layout]
-        out_shape = [jax.ShapeDtypeStruct(s, dt)
-                     for _, _, s, dt in out_layout]
-        outs = pl.pallas_call(
-            kernel, grid=(grid,), in_specs=in_specs, out_specs=out_specs,
-            out_shape=out_shape, interpret=interpret)(*flat)
-        outs = list(outs) if isinstance(outs, (list, tuple)) else [outs]
-
-        # regroup leaves into per-out values (tuples reassemble)
-        result, i = [], 0
+        # regroup planes into per-out values on the engine's carriers
+        it = iter(outs)
+        result = []
         for o in out_nodes:
-            k = len(_elems(o.ty))
-            result.append(tuple(outs[i:i + k]) if k > 1 else outs[i])
-            i += k
+            legs = []
+            for t in _elems(o.ty):
+                shape = type_shape(t)
+                ps = [next(it)[:shape[0]] for _ in np.ndindex(shape[2:])]
+                x = (jnp.stack(ps, axis=-1).reshape(shape) if shape[2:]
+                     else ps[0])
+                dt = _carrier_dtype(t)
+                legs.append(x != 0 if dt == jnp.bool_ else x.astype(dt))
+            result.append(tuple(legs) if isinstance(o.ty, TupleT)
+                          else legs[0])
         return tuple(result)
 
     ops = [n.op for n in node_list]
     note = (f"{name}: fused {len(node_list)} nodes "
             f"({ops[0]}..{ops[-1]}) into one Pallas row-stream "
             f"(grid={grid} blocks x {block} rows)")
-    return Megakernel(name, apply, len(node_list), len(in_list), block,
-                      grid, linebuf, whole_b, float_nodes, len(winsum),
-                      note, flops=flops, io_bytes=io_bytes)
+    return Megakernel(name, apply, len(nodes) - len(consts), len(in_uids),
+                      block, grid, linebuf, float_nodes, len(winsum), note,
+                      flops=flops, io_bytes=io_bytes)
 
 
 def _winsum_geometry(stn: IRNode):
@@ -624,128 +747,125 @@ def _winsum_geometry(stn: IRNode):
     return l, b, abs(t - b) + 1, abs(r - l) + 1      # (l, b, sh, sw)
 
 
-def _winsum_whole(stn: IRNode, x):
-    """Stencil->Reduce(Add) chain on a whole frame: out[i, j] sums input
-    rows i+b..i+t, cols j+l..j+r, zero outside — one reduce_window."""
+def _window_winsum(stn: IRNode, d: Demand, rows):
+    """Stencil->Reduce(Add) chain on a row window: out[j, x] sums input
+    rows j+b..j+b+sh-1, cols x+l..x+l+sw-1 (zero outside the frame) — a
+    row sum of the halo window, then a lane sum of shifted copies."""
     l, b, sh, sw = _winsum_geometry(stn)
-    return jax.lax.reduce_window(
-        x, jnp.asarray(0, x.dtype), jax.lax.add, (sh, sw), (1, 1),
-        padding=((-b, sh - 1 + b), (-l, sw - 1 + l)))
+
+    def box(x):
+        v = x[0:d.size]
+        for dy in range(1, sh):
+            v = v + x[dy:dy + d.size]
+        out = shift_lanes(v, l)
+        for dx in range(1, sw):
+            out = out + shift_lanes(v, l + dx)
+        return out
+
+    return _pmap(box, rows(stn.inputs[0], d.c + b, d.size + sh - 1))
 
 
-def _winsum_window(stn: IRNode, off, s: int, rows):
-    """The same chain on a row window: fetch the halo rows the patch taps
-    (zero-filled outside the frame by ``rows``) and window-reduce them."""
-    l, b, sh, sw = _winsum_geometry(stn)
-    x = rows(stn.inputs[0], off + b, s + sh - 1)
-    return jax.lax.reduce_window(
-        x, jnp.asarray(0, x.dtype), jax.lax.add, (sh, sw), (1, 1),
-        padding=((0, 0), (-l, sw - 1 + l)))
+def _fold(fn, flat: np.ndarray) -> np.ndarray:
+    """Left fold of ``fn`` over the last axis of a planes array."""
+    acc = flat[..., 0]
+    for k in range(1, flat.shape[-1]):
+        acc = _pmap(fn, acc, flat[..., k])
+    return _wrap(acc)
 
 
-def _window_node(n: IRNode, d: Demand, off, rows, whole):
-    """Compute node ``n``'s window rows [off, off+size) from its inputs
-    (``rows`` fetches input windows in virtual row space, ``whole`` holds
-    whole-frame values for broadcast operands)."""
-    p, s = n.params, d.size
+def _argmin(flat: np.ndarray) -> np.ndarray:
+    """First-minimum index over the last axis (np.argmin's tie rule)."""
+    out = _obj(flat.shape[:-1])
+    for idx in np.ndindex(out.shape):
+        best = flat[idx + (0,)]
+        arg = jnp.zeros(best.shape, jnp.int32)
+        for k in range(1, flat.shape[-1]):
+            take = flat[idx + (k,)] < best
+            best = jnp.where(take, flat[idx + (k,)], best)
+            arg = jnp.where(take, jnp.int32(k), arg)
+        out[idx] = arg
+    return out
+
+
+def _window_node(n: IRNode, d: Demand, rows, val, inside, grid: int):
+    """Compute node ``n``'s window (demand ``d``) as planes, from its
+    inputs' windows (``rows``) and constant operands (``val``)."""
+    p, s, c = n.params, d.size, d.c
     if n.op == "Map":
         plans = map_reshape_plans(n.ty, n.input_tys)
         args = []
         for j, (u, plan) in enumerate(zip(n.inputs, plans)):
             if _map_streams_input(n, j):
-                x = jnp.asarray(rows(u, off, s))
-                args.append(x if plan is None
-                            else x.reshape((s,) + tuple(plan[1:])))
-            else:                       # broadcast operand, whole value
-                x = jnp.asarray(whole[u])
+                x = rows(u, c, s)
+                args.append(x if plan is None else x.reshape(plan[2:]))
+            else:                       # broadcast constant operand
+                x = val[u]
                 args.append(x if plan is None else x.reshape(plan))
-        return mk_point_fn(p["fn"])(*args)
+        return _pmap(mk_point_fn(p["fn"]), *args)
     if n.op == "Reduce":
-        return _mk_lower_reduce(n, p, [rows(n.inputs[0], off, s)])
+        x = rows(n.inputs[0], c, s)
+        return _fold(mk_point_fn(p["fn"]), x.reshape(x.shape[:-2] + (-1,)))
     if n.op == "ReducePatch":
-        return _mk_lower_reduce_patch(n, p, [rows(n.inputs[0], off, s)])
+        x = rows(n.inputs[0], c, s)
+        flat = x.reshape((x.shape[0] * x.shape[1],) + x.shape[2:])
+        return _fold(mk_point_fn(p["fn"]), np.moveaxis(flat, 0, -1))
     if n.op == "ArgMin":
-        x = rows(n.inputs[0], off, s)
-        return jnp.argmin(x.reshape(x.shape[:-2] + (-1,)),
-                          axis=-1).astype(jnp.int64)
+        if _is_float(scalar_of(n.input_tys[0])):
+            raise MKUnsupported(f"%{n.uid}: float ArgMin")
+        x = rows(n.inputs[0], c, s)
+        return _argmin(x.reshape(x.shape[:-2] + (-1,)))
     if n.op == "Replicate":
-        x = rows(n.inputs[0], off, s)
-        return jnp.broadcast_to(x[..., None, None],
-                                x.shape + (p["m"], p["n"]))
+        x = rows(n.inputs[0], c, s)
+        return np.broadcast_to(x[..., None, None], x.shape + (p["m"], p["n"]))
     if n.op == "Stack":
-        ins = [rows(u, off, s) for u in n.inputs]
-        return jnp.stack(ins, axis=-1)[..., None, :]
+        ins = [rows(u, c, s) for u in n.inputs]
+        return np.stack(ins, axis=-1)[..., None, :]
     if n.op == "Concat":
-        return tuple(rows(u, off, s) for u in n.inputs)
+        return tuple(rows(u, c, s) for u in n.inputs)
     if n.op == "FanOut":
-        x = rows(n.inputs[0], off, s)
+        x = rows(n.inputs[0], c, s)
         return tuple(x for _ in range(p["n"]))
     if n.op == "FanIn":
-        return rows(n.inputs[0], off, s)
+        return rows(n.inputs[0], c, s)
     if n.op == "TupleIndex":
-        return rows(n.inputs[0], off, s)[p["i"]]
+        return rows(n.inputs[0], c, s)[p["i"]]
     if n.op == "Stencil":
-        return _window_stencil(n, p, off, s, rows)
+        l, r, b, t = p["l"], p["r"], p["b"], p["t"]
+        sw, sh = abs(r - l) + 1, abs(t - b) + 1
+        x = rows(n.inputs[0], c + b, s + sh - 1)
+        out = _obj((sh, sw) + x.shape)
+        for dy in range(sh):
+            for dx in range(sw):
+                for idx in np.ndindex(x.shape):
+                    out[(dy, dx) + idx] = shift_lanes(x[idx][dy:dy + s],
+                                                      l + dx)
+        return out
     if n.op == "Pad":
-        return _window_pad(n, p, off, s, rows)
+        l, rr, t = p["l"], p["r"], p["t"]
+        h_in = type_shape(n.input_tys[0])[0]
+        value = p.get("value", 0)
+        x = rows(n.inputs[0], c - t, s)
+        in_frame = _shift(d, -t).stays_inside(grid, h_in)
+
+        def pad(e):
+            e = pad_lanes(e, l, rr, value)
+            if in_frame:
+                return e
+            return jnp.where(inside(d, e.shape, t, t + h_in), e,
+                             jnp.full((), value, e.dtype))
+        return _pmap(pad, x)
     if n.op == "Crop":
-        x = rows(n.inputs[0], off + p["t"], s)
-        return x[:, p["l"]:x.shape[1] - p["r"]]
+        x = rows(n.inputs[0], c + p["t"], s)
+        return _pmap(lambda e: e[:, p["l"]:e.shape[1] - p["r"]], x)
     if n.op == "Downsample":
         sy, sx = p["sy"], p["sx"]
-        x = rows(n.inputs[0], off * sy, sy * (s - 1) + 1)
-        return x[::sy, ::sx]
+        x = rows(n.inputs[0], c * sy, sy * (s - 1) + 1)
+        return _pmap(lambda e: decimate_lanes(
+            pick_rows(e, [j * sy for j in range(s)]), sx), x)
     if n.op == "Upsample":
         sy, sx = p["sy"], p["sx"]
-        size_in = (s + sy - 2) // sy + 1
-        base = off // sy
-        x = rows(n.inputs[0], base, size_in)
-        if isinstance(off, int):        # static row replication
-            rel = [min((off + i) // sy - base, size_in - 1)
-                   for i in range(s)]
-            out = jnp.concatenate([x[j:j + 1] for j in rel], axis=0)
-        else:
-            rel = (jnp.asarray(off, jnp.int32)
-                   + jnp.arange(s, dtype=jnp.int32)) // sy \
-                - jnp.asarray(base, jnp.int32)
-            out = jnp.take(x, jnp.clip(rel, 0, size_in - 1), axis=0)
-        return jnp.repeat(out, sx, axis=1)
+        base = c // sy
+        x = rows(n.inputs[0], base, (c + s - 1) // sy - base + 1)
+        src = [(c + j) // sy - base for j in range(s)]
+        return _pmap(lambda e: jnp.repeat(pick_rows(e, src), sx, axis=1), x)
     raise MKUnsupported(f"no window lowering for {n.op}")
-
-
-def _window_stencil(n: IRNode, p, off, s: int, rows):
-    """jnp_stencil on a row window: tap dy of output row j reads input
-    virtual row off+b+dy+j, i.e. window rows [off+b, off+b+s+sh-1) of the
-    input (zero-filled outside its frame by construction)."""
-    l, r, b, t = p["l"], p["r"], p["b"], p["t"]
-    sw, sh = abs(r - l) + 1, abs(t - b) + 1
-    x = rows(n.inputs[0], off + b, s + sh - 1)
-    w = x.shape[1]
-    pl_, pr = max(0, -min(l, 0)), max(0, max(r + sw, sw))
-    xp = jnp.pad(x, ((0, 0), (pl_, pr)) + ((0, 0),) * (x.ndim - 2))
-    out_rows = []
-    for dy in range(sh):
-        cols = []
-        for dx in range(sw):
-            ox = l + dx
-            cols.append(xp[dy:dy + s, pl_ + ox:pl_ + ox + w])
-        out_rows.append(jnp.stack(cols, axis=2))
-    return jnp.stack(out_rows, axis=2)
-
-
-def _window_pad(n: IRNode, p, off, s: int, rows):
-    """Pad on a row window: output virtual row y is input row y-t where
-    t <= y < t+h_in, else the pad value; columns pad as in _lower_pad."""
-    l, rr, t = p["l"], p["r"], p["t"]
-    h_in = type_shape(n.input_tys[0])[0]
-    x = rows(n.inputs[0], off - t, s)
-    value = p.get("value", 0)
-    if isinstance(off, int) and off >= t and off + s <= t + h_in:
-        mid = x                         # statically inside: no select
-    else:
-        idx = jnp.asarray(off, jnp.int32) + jnp.arange(s, dtype=jnp.int32)
-        inside = (idx >= t) & (idx < t + h_in)
-        mid = jnp.where(inside.reshape((s,) + (1,) * (x.ndim - 1)), x,
-                        jnp.asarray(value, x.dtype))
-    out = jnp.full((s, x.shape[1] + l + rr) + x.shape[2:], value, x.dtype)
-    return out.at[:, l:l + x.shape[1]].set(mid)

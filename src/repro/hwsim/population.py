@@ -38,7 +38,8 @@ from ..core.buffers import Edge
 from ..core.rigel import RModule
 from .occupancy import EdgeOccupancy, OccupancyTrace
 from .sim import EdgeKey, SimResult
-from .vector import _DONE, _HORIZON, _INF, _RUNNING, _STALL, VectorSim, _has_jax
+from .vector import (_DONE, _HORIZON, _INF, _RUNNING, _STALL, VectorSim,
+                     _has_jax, host_device)
 
 _POP_STATE_KEYS = ("t", "last_progress", "occ", "consumed", "kf", "fr",
                    "launched", "pushed", "credit", "hist", "hwm",
@@ -98,10 +99,9 @@ class PopulationSim:
     def _run_batched(self, horizon: int, stall_limit: int,
                      event_jump: bool) -> Dict[str, np.ndarray]:
         import jax
-        from jax.experimental import enable_x64
 
         b, K = self.base, self.K
-        with enable_x64():
+        with jax.enable_x64(True), jax.default_device(host_device()):
             i64 = np.int64
             as_j = jax.numpy.asarray
             # per-edge need tables pre-sliced so the batched per-cycle
@@ -372,9 +372,8 @@ def _pop_impl(consts, state, frames, H, horizon, stall_limit, sink0,
     return lax.while_loop(cond, body, state)
 
 
-# AOT cache, same rationale as vector._SEG_CACHE: thunk-runtime dispatch
-# overhead dominates the small-op loop body, and every population whose
-# netlist + K match shares one executable
+# AOT cache, same rationale as vector._SEG_CACHE: every population whose
+# netlist + K match shares one executable, compiled for the host device
 _POP_CACHE: Dict[Tuple, object] = {}
 
 
@@ -382,20 +381,12 @@ def _pop_kernel(consts, state, frames, H, horizon, stall_limit, sink0,
                 frame_tokens, jump):
     import jax
 
-    args = (consts, state, frames, H, horizon, stall_limit, sink0,
-            frame_tokens, jump)
+    args = jax.device_put((consts, state, frames, H, horizon, stall_limit,
+                           sink0, frame_tokens, jump), host_device())
     flat, _ = jax.tree_util.tree_flatten(args)
-    key = tuple((np.shape(x), str(np.asarray(x).dtype)) for x in flat)
+    key = tuple((np.shape(x), str(x.dtype)) for x in flat)
     compiled = _POP_CACHE.get(key)
     if compiled is None:
-        lowered = jax.jit(_pop_impl).lower(*args)
-        try:
-            if jax.default_backend() == "cpu":
-                compiled = lowered.compile(
-                    compiler_options={"xla_cpu_use_thunk_runtime": False})
-            else:  # pragma: no cover - CI is CPU-only
-                compiled = lowered.compile()
-        except Exception:  # pragma: no cover - option vanished upstream
-            compiled = lowered.compile()
+        compiled = jax.jit(_pop_impl).lower(*args).compile()
         _POP_CACHE[key] = compiled
     return compiled(*args)

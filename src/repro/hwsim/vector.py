@@ -340,9 +340,7 @@ class VectorSim:
                  event_jump: bool = True
                  ) -> Tuple[dict, List[int], Optional[int]]:
         import jax
-        from jax.experimental import enable_x64
-
-        with enable_x64():
+        with jax.enable_x64(True), jax.default_device(host_device()):
             consts = self._consts()
             s0 = self._initial_state()
             state = tuple(jax.numpy.asarray(s0[k]) for k in _STATE_KEYS)
@@ -608,32 +606,29 @@ def _segment_impl(consts, state, seg_target, frames, H, horizon,
 
 
 # AOT-compiled kernels keyed by the flattened arg signature (shapes+dtypes):
-# every simulation of a same-shaped netlist shares one executable. AOT
-# compilation (rather than plain jax.jit) lets us pass per-executable
-# compiler options: XLA:CPU's default thunk runtime pays ~100ns dispatch per
-# op per loop iteration, which dominates a body of ~50 tiny ops — the
-# legacy inline emitter runs the same kernel ~5x faster.
+# every simulation of a same-shaped netlist shares one executable.  The
+# cycle simulator is a host tool: its operands are committed to the CPU
+# device, so the int64 while-loop compiles and runs there even in a
+# process whose default device is an accelerator.
 _SEG_CACHE: Dict[Tuple, object] = {}
+
+
+def host_device():
+    """The device every cycle-simulator kernel compiles and runs on."""
+    import jax
+    return jax.devices("cpu")[0]
 
 
 def _segment(consts, state, seg_target, frames, H, horizon, stall_limit,
              sink0, jump):
     import jax
 
-    args = (consts, state, seg_target, frames, H, horizon, stall_limit,
-            sink0, jump)
+    args = jax.device_put((consts, state, seg_target, frames, H, horizon,
+                           stall_limit, sink0, jump), host_device())
     flat, _ = jax.tree_util.tree_flatten(args)
     key = tuple((np.shape(x), str(x.dtype)) for x in flat)
     compiled = _SEG_CACHE.get(key)
     if compiled is None:
-        lowered = jax.jit(_segment_impl).lower(*args)
-        try:
-            if jax.default_backend() == "cpu":
-                compiled = lowered.compile(
-                    compiler_options={"xla_cpu_use_thunk_runtime": False})
-            else:  # pragma: no cover - CI is CPU-only
-                compiled = lowered.compile()
-        except Exception:  # pragma: no cover - option vanished upstream
-            compiled = lowered.compile()
+        compiled = jax.jit(_segment_impl).lower(*args).compile()
         _SEG_CACHE[key] = compiled
     return compiled(*args)

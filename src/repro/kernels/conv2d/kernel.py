@@ -31,8 +31,8 @@ def _conv_kernel(x_cur_ref, x_nxt_ref, k_ref, o_ref, *, kh: int, kw: int,
     acc = jnp.zeros((TILE_ROWS, w_out), jnp.int32)
     for dy in range(kh):                  # unrolled taps: VPU adds over the
         for dx in range(kw):              # 128-lane W dimension
-            acc = acc + k[dy, dx] * jax.lax.dynamic_slice(
-                full, (dy, dx), (TILE_ROWS, w_out))
+            acc = acc + k[dy, dx] * full[dy:dy + TILE_ROWS,
+                                         dx:dx + w_out]
     o_ref[...] = (acc >> shift) & 0xFF
 
 
@@ -47,16 +47,17 @@ def conv2d_strips(p: jnp.ndarray, k: jnp.ndarray, *, kh: int, kw: int,
     h = hp - TILE_ROWS
     assert h % TILE_ROWS == 0, (h, TILE_ROWS)
     grid = (h // TILE_ROWS,)
-    return pl.pallas_call(
-        functools.partial(_conv_kernel, kh=kh, kw=kw, w_out=w_out,
-                          shift=shift),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((TILE_ROWS, wp), lambda i: (i, 0)),      # strip i
-            pl.BlockSpec((TILE_ROWS, wp), lambda i: (i + 1, 0)),  # halo strip
-            pl.BlockSpec((kh, kw), lambda i: (0, 0)),             # coeffs
-        ],
-        out_specs=pl.BlockSpec((TILE_ROWS, w_out), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((h, w_out), jnp.int32),
-        interpret=interpret,
-    )(p, p, k)
+    with jax.enable_x64(False):         # 32-bit kernel, int32 index maps
+        return pl.pallas_call(
+            functools.partial(_conv_kernel, kh=kh, kw=kw, w_out=w_out,
+                              shift=shift),
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((TILE_ROWS, wp), lambda i: (i, 0)),  # strip i
+                pl.BlockSpec((TILE_ROWS, wp), lambda i: (i + 1, 0)),  # halo
+                pl.BlockSpec((kh, kw), lambda i: (0, 0)),         # coeffs
+            ],
+            out_specs=pl.BlockSpec((TILE_ROWS, w_out), lambda i: (i, 0)),
+            out_shape=jax.ShapeDtypeStruct((h, w_out), jnp.int32),
+            interpret=interpret,
+        )(p, p, k)

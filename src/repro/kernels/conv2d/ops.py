@@ -1,13 +1,10 @@
 """Public wrapper: pads/aligns, invokes the Pallas kernel, crops."""
 from __future__ import annotations
 
-import os
-
 import jax.numpy as jnp
 
+from ..stream import interpret_default
 from .kernel import TILE_ROWS, conv2d_strips
-
-INTERPRET = os.environ.get("REPRO_PALLAS_REAL", "0") != "1"
 
 
 def conv2d_stencil(p, k, shift: int = 11):
@@ -28,7 +25,7 @@ def conv2d_stencil(p, k, shift: int = 11):
     extra_rows = rows_needed - p.shape[0]
     p2 = jnp.pad(p, ((0, max(0, extra_rows)), (0, 0)))
     out = conv2d_strips(p2, k, kh=kh, kw=kw, w_out=w, shift=shift,
-                        interpret=INTERPRET)
+                        interpret=interpret_default())
     return out[:h]
 
 

@@ -1,13 +1,10 @@
 """Public wrappers: (B, S, H, D) layout <-> kernel layout, prefill+decode."""
 from __future__ import annotations
 
-import os
-
 import jax.numpy as jnp
 
+from ..stream import interpret_default
 from .kernel import flash_bhsd
-
-INTERPRET = os.environ.get("REPRO_PALLAS_REAL", "0") != "1"
 
 
 def flash_attention_tpu(q, k, v, *, causal: bool = True, window=None,
@@ -22,7 +19,7 @@ def flash_attention_tpu(q, k, v, *, causal: bool = True, window=None,
     bq_ = min(bq, max(8, Sq))
     bk_ = min(bk, max(8, Skv))
     out = flash_bhsd(qf, kf, vf, causal=causal, window=window, bq=bq_,
-                     bk=bk_, g=g, interpret=INTERPRET)
+                     bk=bk_, g=g, interpret=interpret_default())
     return jnp.moveaxis(out.reshape(B, H, Sq, D), 1, 2)
 
 

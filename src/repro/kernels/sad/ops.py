@@ -1,13 +1,10 @@
 """Public wrapper for the SAD disparity kernel."""
 from __future__ import annotations
 
-import os
-
 import jax.numpy as jnp
 
+from ..stream import interpret_default
 from .kernel import TILE_ROWS, sad_strips
-
-INTERPRET = os.environ.get("REPRO_PALLAS_REAL", "0") != "1"
 
 
 def sad_disparity(l, r, *, nd: int = 64, bh: int = 8, bw: int = 8):
@@ -23,7 +20,7 @@ def sad_disparity(l, r, *, nd: int = 64, bh: int = 8, bw: int = 8):
         l = jnp.pad(l, ((0, extra), (0, 0)))
         r = jnp.pad(r, ((0, extra), (0, 0)))
     out = sad_strips(l, r, nd=nd, bh=bh, bw=bw, w_out=w,
-                     interpret=INTERPRET)
+                     interpret=interpret_default())
     return out[:h]
 
 

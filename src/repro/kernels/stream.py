@@ -1,105 +1,105 @@
-"""Shared line-buffer / block-spec utilities for row-streaming Pallas
-kernels (the conv2d/sad strip kernels and the megakernel emitter).
+"""Shared row-streaming plumbing for the Pallas kernels (the conv2d/sad
+strip kernels and the megakernel emitter).
 
 A streaming kernel walks the frame in row blocks: the grid iterates output
-row blocks, every input lives whole in VMEM (a full-array BlockSpec), and
-each node of the fused chain keeps only the *window* of rows its consumers
-demand — the software mirror of the hardware model's line buffers.  The
-helpers here are the window plumbing: block specs, clip-gather row
-extraction with the executor's zero-fill-outside-frame semantics, and
-byte accounting for the VMEM line-buffer report.
+row blocks, inputs arrive as row strips through ``BlockSpec`` index maps
+(strip i plus its halo neighbours), and every value inside the kernel is a
+2-D ``(rows, width)`` array of a 32-bit dtype — the shape a TPU vector unit
+works in.  The helpers here are the in-kernel window operations, written
+only with forms the TPU's kernel compiler accepts: static slices,
+concatenation, lane gathers within one 128-lane register, and ``repeat``.
 """
 from __future__ import annotations
 
-import os
 from typing import Tuple
 
+import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
 
 def interpret_default() -> bool:
-    """Pallas kernels run in interpret mode unless REPRO_PALLAS_REAL=1
-    (the real-TPU escape hatch shared by every resident kernel)."""
-    return os.environ.get("REPRO_PALLAS_REAL", "0") != "1"
+    """The one interpret-mode decision for every Pallas kernel: kernels are
+    interpreted exactly when the default backend is the CPU (tests, the
+    sandbox); on an accelerator they always compile."""
+    return jax.default_backend() == "cpu"
 
 
 # rows per grid step for megakernel emission: deep enough to amortize the
-# per-block gather/compute overhead, shallow enough that stencil halos and
-# resampling-skew windows stay small multiples of it
+# per-block overhead, shallow enough that stencil halos stay small
+# multiples of it.  SUBLANES is the row tiling of a 32-bit TPU register: a
+# row strip handed to a kernel through a BlockSpec must be a multiple.
 MK_BLOCK_ROWS = 8
+SUBLANES = 8
+LANES = 128
 
 
-def whole_spec(shape: Tuple[int, ...]) -> pl.BlockSpec:
-    """Full-array BlockSpec: the operand is resident in VMEM for every
-    grid step (how streaming kernels see their input frames)."""
-    nd = len(shape)
-    return pl.BlockSpec(shape, lambda i, _n=nd: (0,) * _n)
-
-
-def row_block_spec(block_rows: int, shape: Tuple[int, ...]) -> pl.BlockSpec:
-    """Output BlockSpec for grid step i -> rows [i*block_rows, ...) of an
-    output of ``shape`` (trailing dims whole per block)."""
-    nd = len(shape)
-    return pl.BlockSpec((block_rows,) + tuple(shape[1:]),
-                        lambda i, _n=nd: (i,) + (0,) * (_n - 1))
-
-
-def _i32(x):
-    return jnp.asarray(x, jnp.int32)
-
-
-def _zero_rows_pad(x, top: int, bottom: int):
-    if top == 0 and bottom == 0:
+def shift_lanes(x, k: int, fill=0):
+    """``out[:, j] = x[:, j + k]``, ``fill`` where ``j + k`` leaves
+    ``[0, width)`` — a stencil tap's column offset with the executor's
+    zero fill outside the frame."""
+    s, w = x.shape
+    if k == 0:
         return x
-    return jnp.pad(x, ((top, bottom),) + ((0, 0),) * (x.ndim - 1))
+    if abs(k) >= w:
+        return jnp.full((s, w), fill, x.dtype)
+    pad = jnp.full((s, abs(k)), fill, x.dtype)
+    if k > 0:
+        return jnp.concatenate([x[:, k:], pad], axis=1)
+    return jnp.concatenate([pad, x[:, :w + k]], axis=1)
 
 
-def take_rows(full, off, size: int):
-    """Rows [off, off+size) of a whole-frame array in *virtual* row space:
-    rows outside [0, h) read as zero (executor zero-fill semantics — the
-    only out-of-frame demand generators are stencil halos, whose taps are
-    defined to read zero).  ``off`` may be a traced scalar; a static int
-    offset takes the slice/pad fast path (no gather, no select — XLA
-    fuses slices where it can't fuse gathers)."""
-    h = full.shape[0]
-    if isinstance(off, int):
-        lo, hi = max(0, off), min(h, off + size)
-        if lo >= hi:
-            return jnp.zeros((size,) + tuple(full.shape[1:]), full.dtype)
-        return _zero_rows_pad(full[lo:hi], lo - off, off + size - hi)
-    idx = _i32(off) + jnp.arange(size, dtype=jnp.int32)
-    win = jnp.take(full, jnp.clip(idx, 0, h - 1), axis=0)
-    valid = (idx >= 0) & (idx < h)
-    return jnp.where(valid.reshape((size,) + (1,) * (win.ndim - 1)), win,
-                     jnp.zeros((), win.dtype))
+def pad_lanes(x, left: int, right: int, fill=0):
+    s = x.shape[0]
+    parts = ([jnp.full((s, left), fill, x.dtype)] if left else []) + [x] \
+        + ([jnp.full((s, right), fill, x.dtype)] if right else [])
+    return jnp.concatenate(parts, axis=1) if len(parts) > 1 else x
 
 
-def window_rows(win, rel_off, size: int):
-    """Rows [rel_off, rel_off+size) of an already-extracted window whose
-    coverage is guaranteed by demand propagation (no bounds masking)."""
-    if isinstance(rel_off, int):
-        return win[rel_off:rel_off + size]
-    idx = _i32(rel_off) + jnp.arange(size, dtype=jnp.int32)
-    return jnp.take(win, jnp.clip(idx, 0, win.shape[0] - 1), axis=0)
+def pick_rows(x, rows):
+    """Rows ``x[rows[0]], x[rows[1]], ...`` (static indices) stacked in
+    order: runs of one repeated source row become one broadcast."""
+    parts, j = [], 0
+    while j < len(rows):
+        k = j
+        while k + 1 < len(rows) and rows[k + 1] == rows[j]:
+            k += 1
+        r = rows[j]
+        if k > j:
+            parts.append(jnp.broadcast_to(x[r:r + 1], (k - j + 1,)
+                                          + x.shape[1:]))
+        else:
+            parts.append(x[r:r + 1])
+        j = k + 1
+    return jnp.concatenate(parts, axis=0) if len(parts) > 1 else parts[0]
 
 
-def mask_outside_frame(win, off, h: int):
-    """Zero the rows of ``win`` (covering virtual rows [off, off+size))
-    that fall outside the node's own frame [0, h)."""
-    size = win.shape[0]
-    if isinstance(off, int):
-        if off >= 0 and off + size <= h:
-            return win
-        lo, hi = max(0, off), min(h, off + size)
-        if lo >= hi:
-            return jnp.zeros_like(win)
-        return _zero_rows_pad(win[lo - off:hi - off], lo - off,
-                              off + size - hi)
-    idx = _i32(off) + jnp.arange(size, dtype=jnp.int32)
-    valid = (idx >= 0) & (idx < h)
-    return jnp.where(valid.reshape((size,) + (1,) * (win.ndim - 1)), win,
-                     jnp.zeros((), win.dtype))
+def decimate_lanes(x, sx: int):
+    """``x[:, ::sx]`` for a stride dividing the 128-lane register: output
+    register k gathers every sx-th lane of input registers k*sx .. k*sx+sx-1
+    (a per-register lane gather, which the TPU has; a strided lane slice it
+    has not)."""
+    if sx == 1:
+        return x
+    s, w = x.shape
+    w_out = -(-w // sx)
+    nv = -(-w // LANES)
+    x = pad_lanes(x, 0, nv * LANES - w)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (s, LANES), 1)
+    idx = (lane * sx) % LANES
+    outs = []
+    for k in range(-(-w_out // LANES)):
+        acc = None
+        for q in range(sx):
+            src = k * sx + q
+            if src >= nv:
+                break
+            g = jnp.take_along_axis(x[:, src * LANES:(src + 1) * LANES],
+                                    idx, axis=1)
+            acc = g if acc is None else jnp.where(lane // (LANES // sx) == q,
+                                                  g, acc)
+        outs.append(acc)
+    out = jnp.concatenate(outs, axis=1) if len(outs) > 1 else outs[0]
+    return out[:, :w_out]
 
 
 def nbytes(shape: Tuple[int, ...], dtype) -> int:

@@ -25,6 +25,7 @@ def main(argv: Optional[List[str]] = None) -> int:
     import numpy as np
 
     from ..apps import BENCH_CASES
+    from ..cache import enable_compile_cache
     from ..core import CompileOptions, compile_pipeline
     from . import FrameServer, ServeConfig
 
@@ -49,6 +50,7 @@ def main(argv: Optional[List[str]] = None) -> int:
     if not args.status:
         ap.error("nothing to do (pass --status)")
 
+    enable_compile_cache()
     apps = args.app or ["convolution", "stereo"]
     cfg = ServeConfig(max_batch=args.max_batch,
                       warmup=not args.no_warmup)

@@ -66,7 +66,9 @@ class BatchDispatcher:
                pad_to: Optional[int] = None) -> InflightBatch:
         batch, _ = stack_frames(reqs, pad_to=pad_to)
         dev_batch, _n = device_put_batch(batch, self.sharding)
+        mesh = self.sharding.mesh if self.sharding is not None else None
         with _quiet_donation(self.donate):
             out = self.compiled.run_batch_device(dev_batch,
-                                                 donate=self.donate)
+                                                 donate=self.donate,
+                                                 mesh=mesh)
         return InflightBatch(reqs, out, len(reqs), time.perf_counter())

@@ -1,11 +1,12 @@
 """Frame-axis sharding for the serving layer.
 
 A stacked batch carries frames on axis 0; with more than one device the
-axis is laid out across a 1-d ``jax.sharding.Mesh`` ("frames") so the
-vmapped pipeline programs run one shard per device under XLA's SPMD
-partitioner.  With a single device (the common CPU CI case) everything
-degrades transparently to a plain committed ``device_put`` — callers never
-branch on device count.
+axis is laid out across a 1-d ``jax.sharding.Mesh`` ("frames") and the
+engine runs every vmapped pipeline program once per device on its local
+frames (``shard_map``: a Pallas kernel is not partitioned by XLA's SPMD
+partitioner, so the split is made explicit).  With a single device (the
+common CPU CI case) everything degrades transparently to a plain committed
+``device_put`` — callers never branch on device count.
 
 Transfers run under the x64 context so int64 frame buffers keep the
 executor's integer carrier width instead of being canonicalized to int32.
@@ -16,8 +17,9 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import numpy as np
-from jax.experimental import enable_x64
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+from ..core.lowering.engine import FRAME_AXIS
 
 
 def frame_sharding(devices=None) -> Optional[NamedSharding]:
@@ -26,8 +28,8 @@ def frame_sharding(devices=None) -> Optional[NamedSharding]:
     devs = list(devices) if devices is not None else jax.devices()
     if len(devs) <= 1:
         return None
-    mesh = Mesh(np.array(devs), ("frames",))
-    return NamedSharding(mesh, PartitionSpec("frames"))
+    mesh = Mesh(np.array(devs), (FRAME_AXIS,))
+    return NamedSharding(mesh, PartitionSpec(FRAME_AXIS))
 
 
 def pad_frames(batch: Dict[str, Any], multiple: int
@@ -72,5 +74,5 @@ def device_put_batch(batch: Dict[str, Any],
             return jax.device_put(v, sharding)
         return jax.device_put(v)
 
-    with enable_x64():
+    with jax.enable_x64(True):
         return {k: put(v) for k, v in batch.items()}, n

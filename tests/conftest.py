@@ -1,9 +1,9 @@
 """Shared tier-1 fixtures and session-level speedups.
 
-- Repo-local persistent XLA compilation cache: repeated tier-1 runs skip
-  recompiling the heavy per-arch model tests (REPRO_NO_JAX_CACHE=1
-  disables). Must be configured via env vars before jax is imported, and
-  propagates to the subprocess tests in test_perf_variants.
+- Persistent XLA compilation cache under the package's one rule
+  (repro/cache.py): ``JAX_COMPILATION_CACHE_DIR`` where set, else
+  ``<checkout>/.cache/jax``.  Exported as env vars before jax is imported,
+  so the subprocess tests in test_perf_variants inherit it.
 - Session-scoped compiled designs shared across test modules, so the
   full-size CONVOLUTION pipeline and the four small app cases are each
   compiled once.
@@ -13,13 +13,12 @@ from fractions import Fraction
 
 import pytest
 
-if not os.environ.get("REPRO_NO_JAX_CACHE"):
-    _cache = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), ".cache", "jax")
-    os.makedirs(_cache, exist_ok=True)
-    os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", _cache)
-    os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES", "-1")
-    os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0.1")
+from repro.cache import ENV, compile_cache_dir
+
+os.makedirs(compile_cache_dir(), exist_ok=True)
+os.environ[ENV] = compile_cache_dir()
+os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES", "-1")
+os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0.1")
 
 
 @pytest.fixture(scope="session")

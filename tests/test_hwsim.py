@@ -183,6 +183,27 @@ def test_vector_engine_is_default(designs):
                         options=SimOptions(engine="vector"))
 
 
+def test_cycle_simulator_compiles_for_the_host_device(designs):
+    """The cycle simulator is a host tool: the vector and population
+    engines compile and run on the CPU device, so simulate, optimize_fifos
+    and explore never take the accelerator of a process that holds one."""
+    import jax
+
+    from repro.hwsim import PopulationSim, population, vector
+    design, _, _ = designs["stereo"]
+    depths = dict(design.fifo.depth)
+    VectorSim(design.modules, design.edges, depths).run(jit=True)
+    PopulationSim(design.modules, design.edges, [depths, depths]).run()
+    cpu = jax.devices("cpu")[0]
+    compiled = list(vector._SEG_CACHE.values()) \
+        + list(population._POP_CACHE.values())
+    assert len(compiled) >= 2
+    for exe in compiled:
+        devs = {d for s in jax.tree_util.tree_leaves(exe.input_shardings)
+                for d in s.device_set}
+        assert devs == {cpu}
+
+
 def test_vector_unbounded_matches_scalar(designs):
     design, _, _ = designs["stereo"]
     ref = simulate(design, engine="scalar", unbounded=True)

@@ -15,6 +15,51 @@ from repro.kernels.sad.ref import sad_ref
 rng = np.random.RandomState(3)
 
 
+def test_every_kernel_wrapper_interprets_on_cpu_and_compiles_elsewhere(
+        monkeypatch):
+    """The interpret decision is made once, from the platform, when a
+    kernel is called: every kernel wrapper hands ``interpret=True`` to
+    Pallas on the CPU backend and ``False`` on an accelerator."""
+    from jax.experimental import pallas as pl
+
+    from repro.core import Array2d, Input, Max, Reduce, Stencil, UInt
+    from repro.core.lowering import lower_pipeline
+    from repro.kernels import stream
+
+    seen = []
+    real = pl.pallas_call
+
+    def spy(*args, **kw):
+        seen.append(kw["interpret"])
+        return real(*args, **kw)
+
+    def call_every_wrapper():
+        seen.clear()
+        jax.clear_caches()              # re-trace the jitted wrappers
+        x = Input(Array2d(UInt(8), 16, 8), "x")
+        lp = lower_pipeline(Reduce(Max)(Stencil(0, 1, 0, 0)(x)),
+                            backend="pallas")
+        with jax.enable_x64(True):
+            jax.eval_shape(lp.megakernels[0].apply,
+                           jax.ShapeDtypeStruct((8, 16), jnp.int64))
+        jax.eval_shape(lambda p, k: conv2d_stencil(p, k),
+                       jax.ShapeDtypeStruct((15, 23), jnp.int32),
+                       jax.ShapeDtypeStruct((8, 8), jnp.int32))
+        jax.eval_shape(lambda a, b: sad_disparity(a, b, nd=4),
+                       jax.ShapeDtypeStruct((15, 26), jnp.int32),
+                       jax.ShapeDtypeStruct((15, 26), jnp.int32))
+        q = jax.ShapeDtypeStruct((1, 8, 2, 128), jnp.float32)
+        jax.eval_shape(flash_attention_tpu, q, q, q)
+        return list(seen)
+
+    monkeypatch.setattr(pl, "pallas_call", spy)
+    assert stream.interpret_default() is True
+    assert call_every_wrapper() == [True] * 4
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert stream.interpret_default() is False
+    assert call_every_wrapper() == [False] * 4
+
+
 @pytest.mark.parametrize("h,w,kh,kw,shift", [
     # one case per coverage class: lane-aligned 8x8, small 3x3, odd rows,
     # mid-size 5x5 (redundant shapes trimmed for tier-1 wall time)

@@ -56,7 +56,7 @@ def test_second_moment_window_fusions_fire(app, expected, lowering_cases):
     """The FLOW second-moment block (Ix·Iy products -> box-sum) fuses into
     jnp window-reduces on the jax backend.  On pallas, megakernel emission
     subsumes the window_sum rule: the chains stream inside the fused
-    kernel, where the same box sums lower to in-kernel reduce_windows."""
+    kernel, where the same box sums lower to separable row/lane sums."""
     design, _ = lowering_cases[app]
     lp = design.lower("jax")
     assert len(lp.fusions) == expected, lp.notes
@@ -64,7 +64,7 @@ def test_second_moment_window_fusions_fire(app, expected, lowering_cases):
 
     lp = design.lower("pallas")
     assert not any(d.kernel == "window_sum" for d in lp.fusions.values())
-    assert any(f"{expected} box-sum chain(s) via reduce_window" in n
+    assert any(f"{expected} box-sum chain(s) as separable sums" in n
                for n in lp.notes), lp.notes
 
 

@@ -20,7 +20,6 @@ import numpy as np
 import pytest
 
 import jax
-from jax.experimental import enable_x64
 
 from repro.core import (AddMSBs, Array2d, Const, Crop, Downsample, Input,
                         Map, Max, Mul, Pad, Reduce, Stencil, UInt)
@@ -113,7 +112,7 @@ def test_streaming_grid_matches_whole_frame_emission(app, lowering_cases):
         mk4 = emit_megakernel(lp.ir, t.nodes, t.in_uids, t.out_uids,
                               name=t.mk.name + "_s4", block_rows=4)
         invals = [vals[u] for u in t.in_uids]
-        with enable_x64():
+        with jax.enable_x64(True):
             a = jax.jit(t.mk.apply)(*invals)
             b = jax.jit(mk4.apply)(*invals)
         for x, y in zip(a, b):

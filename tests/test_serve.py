@@ -250,9 +250,12 @@ def test_server_submit_unknown_app_raises(lowering_cases):
         srv.submit({"x": np.zeros((2, 2))})      # closed
 
 
-def test_multi_device_sharded_serving_bit_exact():
-    """Frame-axis sharding across 8 (forced host) devices stays bit-exact;
-    runs in a subprocess so this process keeps its single-device view."""
+@pytest.mark.parametrize("backend", ["jax", "pallas"])
+def test_multi_device_sharded_serving_bit_exact(backend):
+    """Frame-axis sharding across 8 (forced host) devices stays bit-exact
+    on both backends (on pallas every device runs the megakernel on its
+    own frames); runs in a subprocess so this process keeps its
+    single-device view."""
     import os
     import subprocess
     import sys
@@ -263,12 +266,11 @@ def test_multi_device_sharded_serving_bit_exact():
     # don't contend with the parent process on the persistent XLA cache
     # (conftest.py points both at .cache/jax, and the 8-device layout's
     # entries are useless to the single-device parent anyway)
-    env["REPRO_NO_JAX_CACHE"] = "1"
     for k in list(env):
         if k.startswith("JAX_COMPILATION_CACHE") or \
                 k.startswith("JAX_PERSISTENT_CACHE"):
             env.pop(k)
-    code = textwrap.dedent("""
+    code = f"BACKEND = {backend!r}\n" + textwrap.dedent("""
         import numpy as np, jax
         assert len(jax.devices()) == 8
         from repro.apps import BENCH_CASES
@@ -279,7 +281,7 @@ def test_multi_device_sharded_serving_bit_exact():
         d = compile_pipeline(uf)
         frames = [inputs_fn(np.random.RandomState(i)) for i in range(11)]
         cfg = ServeConfig(max_batch=8, max_delay_ms=20.0, donate=True)
-        with d.serve(config=cfg) as srv:
+        with d.serve(backend=BACKEND, config=cfg) as srv:
             outs = [f.result(timeout=300) for f in srv.submit_many(frames)]
         for fr, o in zip(frames, outs):
             ref = evaluate(d.out_val, fr)

@@ -118,3 +118,36 @@ def test_optimize_fifos_options(conv_design_t1):
         old = conv_design_t1.optimize_fifos(frames=2)
     new = conv_design_t1.optimize_fifos(options=SimOptions(frames=2))
     assert old == new
+
+
+@pytest.mark.parametrize("from_env", [True, False], ids=["env", "checkout"])
+def test_compile_cache_rule(from_env, tmp_path):
+    """The persistent compilation cache lives in $JAX_COMPILATION_CACHE_DIR
+    where that is set, else at the fixed <checkout>/.cache/jax, and a
+    compile writes its entry there (a fresh process: JAX reads the
+    variable when it is imported)."""
+    import os
+    import subprocess
+    import sys
+    checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = {k: v for k, v in os.environ.items()
+           if not k.startswith(("JAX_COMPILATION_CACHE",
+                                "JAX_PERSISTENT_CACHE"))}
+    env.update(PYTHONPATH="src", JAX_PLATFORMS="cpu",
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
+               JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES="-1")
+    want = str(tmp_path) if from_env \
+        else os.path.join(checkout, ".cache", "jax")
+    if from_env:
+        env["JAX_COMPILATION_CACHE_DIR"] = want
+    code = ("import os, jax, jax.numpy as jnp\n"
+            "from repro.cache import enable_compile_cache\n"
+            "path = enable_compile_cache()\n"
+            "assert jax.config.jax_compilation_cache_dir == path\n"
+            "jax.jit(lambda x: x * 7 - 3)(jnp.arange(11)).block_until_ready()\n"
+            "print(path)\n")
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, timeout=300, env=env, cwd=checkout)
+    assert r.returncode == 0, r.stderr[-2000:]
+    assert r.stdout.split()[-1] == want
+    assert os.listdir(want)
