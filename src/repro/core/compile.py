@@ -30,6 +30,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from .. import spans
 from . import buffers as buf
 from . import schedule as sched
 from .executor import evaluate
@@ -348,8 +349,10 @@ class HWDesign:
         if key not in self._lowered:
             # lazy import: numpy-only flows stay jax-free
             from .lowering import lower_pipeline
-            lp = lower_pipeline(self.out_val, backend=b, debug=debug,
-                                megakernel=megakernel, per_node=per_node)
+            with spans.span("compile.lower"):
+                lp = lower_pipeline(self.out_val, backend=b, debug=debug,
+                                    megakernel=megakernel,
+                                    per_node=per_node)
             self._lowered[key] = lp
             self.notes.extend(lp.notes)
         return self._lowered[key]
@@ -528,6 +531,14 @@ def compile_pipeline(uf: UserFunction, T: Fraction = Fraction(1),
              manual_fifo_overrides=manual_fifo_overrides, backend=backend,
              sim_frames=sim_frames, sim_guard=sim_guard),
         "compile_pipeline")
+    with spans.span("compile.pipeline"):
+        return _map_pipeline(uf, T, opt)
+
+
+def _map_pipeline(uf: UserFunction, T: Fraction,
+                  opt: CompileOptions) -> HWDesign:
+    """The mapping behind :func:`compile_pipeline`: interface, rates,
+    modules, conversions and the FIFO solve."""
     backend = opt.backend
     include_burst = opt.include_burst
     manual_fifo_overrides = opt.manual_fifo_overrides

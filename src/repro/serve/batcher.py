@@ -53,6 +53,7 @@ class FrameRequest:
     enqueue_t: float
     future: Any = None                # concurrent.futures.Future (or None)
     priority: int = 1                 # admission.NORMAL (0=high .. 2=low)
+    rid: int = -1                     # request id in spans (-1: warm-up)
 
 
 def _stack(leaves: List[Any]):

@@ -19,10 +19,12 @@ process-globally.
 from __future__ import annotations
 
 import contextlib
-import time
 import warnings
 from typing import Any, List, Optional
 
+import jax
+
+from .. import spans
 from .batcher import FrameRequest, split_frames, stack_frames
 from .sharding import device_put_batch
 
@@ -41,17 +43,20 @@ def _quiet_donation(donate: bool):
 class InflightBatch:
     """A dispatched batch: device-side results plus the requests awaiting
     them.  ``wait()`` performs the blocking device→host readback and
-    returns per-frame numpy outputs (padding rows dropped)."""
+    returns per-frame numpy outputs (padding rows dropped); its span
+    carries the batch id ``bid`` and ``n`` real frames."""
 
-    def __init__(self, reqs: List[FrameRequest], device_out: Any, n: int,
-                 t_dispatch: float):
+    def __init__(self, reqs: List[FrameRequest], device_out: Any, bid: int):
         self.reqs = reqs
         self._out = device_out
-        self._n = n
-        self.t_dispatch = t_dispatch
+        self.bid = bid
 
     def wait(self) -> List[Any]:
-        return split_frames(self._out, self._n)
+        with spans.span("serve.d2h", bid=self.bid, n=len(self.reqs)):
+            if spans.enabled():
+                spans.count("serve.d2h_bytes", sum(
+                    x.nbytes for x in jax.tree_util.tree_leaves(self._out)))
+            return split_frames(self._out, len(self.reqs))
 
 
 class BatchDispatcher:
@@ -62,13 +67,17 @@ class BatchDispatcher:
         self.sharding = sharding
         self.donate = donate
 
-    def submit(self, reqs: List[FrameRequest],
-               pad_to: Optional[int] = None) -> InflightBatch:
-        batch, _ = stack_frames(reqs, pad_to=pad_to)
-        dev_batch, _n = device_put_batch(batch, self.sharding)
+    def submit(self, reqs: List[FrameRequest], pad_to: Optional[int] = None,
+               bid: int = -1) -> InflightBatch:
+        """Transfer and enqueue one batch; ``bid`` tags its spans."""
+        ids = {"bid": bid, "n": len(reqs)}
+        with spans.span("serve.h2d", **ids):
+            batch, _ = stack_frames(reqs, pad_to=pad_to)
+            dev_batch, _n = device_put_batch(batch, self.sharding)
         mesh = self.sharding.mesh if self.sharding is not None else None
-        with _quiet_donation(self.donate):
+        with spans.span("serve.enqueue", **ids), \
+                _quiet_donation(self.donate):
             out = self.compiled.run_batch_device(dev_batch,
                                                  donate=self.donate,
                                                  mesh=mesh)
-        return InflightBatch(reqs, out, len(reqs), time.perf_counter())
+        return InflightBatch(reqs, out, bid)

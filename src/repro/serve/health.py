@@ -45,7 +45,6 @@ class AppHealth:
     frames_in: int = 0
     frames_out: int = 0
     batches: int = 0
-    last_dispatch_t: float = 0.0
     # batch-occupancy histogram: real (unpadded) batch size -> count
     batch_occupancy: collections.Counter = field(
         default_factory=collections.Counter)
@@ -92,11 +91,10 @@ class HealthMonitor:
         return self.live and self._ready
 
     # ---- accounting hooks ----
-    def record_batch(self, app: str, n_real: int, now: float) -> None:
+    def record_batch(self, app: str, n_real: int) -> None:
         h = self.app(app)
         h.batches += 1
         h.batch_occupancy[n_real] += 1
-        h.last_dispatch_t = now
 
     def record_done(self, app: str, latency_s: float) -> None:
         h = self.app(app)

@@ -31,6 +31,10 @@ replayable :class:`~repro.serve.health.ServeTrace` that feeds the cycle
 engine's ingest model (``replay_trace_ingest``) with the *measured*
 arrival process.
 
+Every stage of a batch (host-to-device, enqueue, readback, resolution)
+and each frame's wait in the queue is timed by a span (repro/spans.py),
+on the profiler's clock while a trace runs.
+
 The server owns a background thread running the event loop, so
 synchronous callers (tests, benchmarks, request handlers) just call
 ``submit`` and get a ``concurrent.futures.Future``.
@@ -40,12 +44,14 @@ from __future__ import annotations
 import asyncio
 import collections
 import concurrent.futures
+import itertools
 import threading
 import time
 import warnings
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional
 
+from .. import spans
 from .admission import (NORMAL, PRIORITIES, AdmissionController, Overloaded,
                         QoSPolicy)
 from .batcher import (FrameRequest, MicroBatcher, frame_signature,
@@ -205,6 +211,8 @@ class FrameServer:
         self._resident = 0            # admitted frames not yet retired
         self._rlock = threading.Lock()
         self._get_task: Optional[asyncio.Task] = None
+        self._rids = itertools.count()    # request ids (spans)
+        self._bids = itertools.count()    # batch ids (spans)
 
     # ---- setup ----
     def register(self, design, name: Optional[str] = None,
@@ -264,10 +272,10 @@ class FrameServer:
                 for inputs in a.warm_inputs]
         sizes = self._warm_sizes()
         self.stats.warmup_total += len(work) * len(sizes)
-        t0 = time.perf_counter()
-        for name, inputs in work:
-            self._warm_signature(name, inputs, count=False)
-        self.stats.warmup_s += time.perf_counter() - t0
+        with spans.span("serve.warmup") as sp:
+            for name, inputs in work:
+                self._warm_signature(name, inputs, count=False)
+        self.stats.warmup_s += sp.seconds
 
     def _warm_signature(self, app: str, inputs: Dict[str, Any],
                         count: bool = True) -> None:
@@ -289,9 +297,9 @@ class FrameServer:
         every batch size traffic can produce (the pow2 padding buckets up
         to ``max_batch``), synchronously through the dispatcher — so live
         traffic never pays an XLA compile."""
-        t0 = time.perf_counter()
-        self._warm_signature(app or self._default_app, inputs)
-        self.stats.warmup_s += time.perf_counter() - t0
+        with spans.span("serve.warmup") as sp:
+            self._warm_signature(app or self._default_app, inputs)
+        self.stats.warmup_s += sp.seconds
 
     # ---- client surface ----
     def submit(self, inputs: Dict[str, Any], app: Optional[str] = None,
@@ -330,7 +338,7 @@ class FrameServer:
             self._resident += 1
         fut: concurrent.futures.Future = concurrent.futures.Future()
         req = FrameRequest(name, inputs, frame_signature(inputs),
-                           now, fut, priority=level)
+                           now, fut, priority=level, rid=next(self._rids))
         cf = asyncio.run_coroutine_threadsafe(self._queue.put(req),
                                               self._loop)
         # the put blocks while the request FIFO is full (backpressure) —
@@ -582,8 +590,14 @@ class FrameServer:
                   inflight: collections.deque) -> None:
         app = self._apps[reqs[0].app]
         pad_to = batcher.pad_target(len(reqs))
+        bid = next(self._bids)
+        if spans.enabled():
+            t = time.perf_counter()
+            for r in reqs:
+                spans.interval("serve.queue", r.enqueue_t, t,
+                               rid=r.rid, bid=bid)
         try:
-            handle = app.dispatcher.submit(reqs, pad_to=pad_to)
+            handle = app.dispatcher.submit(reqs, pad_to=pad_to, bid=bid)
         except Exception as e:                  # bad frame: fail the batch
             self._retire(len(reqs))
             for r in reqs:
@@ -598,8 +612,7 @@ class FrameServer:
         self.stats.size_flushes = batcher.size_flushes
         self.stats.deadline_flushes = batcher.deadline_flushes
         self.stats.topup_flushes = batcher.topup_flushes
-        self.health.record_batch(reqs[0].app, len(reqs),
-                                 time.perf_counter())
+        self.health.record_batch(reqs[0].app, len(reqs))
         # the handle rides along so the crash path can fail its requests'
         # futures if the task is cancelled before _readback resolves them
         task = asyncio.ensure_future(self._readback(handle))
@@ -619,11 +632,13 @@ class FrameServer:
         finally:
             self._wake.set()          # a compute slot is (about to be) free
         now = time.perf_counter()
-        for r, out in zip(handle.reqs, outs):
-            if r.future is not None:
-                r.future.set_result(out)
-            self.stats.latencies.append(now - r.enqueue_t)
-            self.health.record_done(r.app, now - r.enqueue_t)
+        with spans.span("serve.resolve", bid=handle.bid,
+                        n=len(handle.reqs)):
+            for r, out in zip(handle.reqs, outs):
+                if r.future is not None:
+                    r.future.set_result(out)
+                self.stats.latencies.append(now - r.enqueue_t)
+                self.health.record_done(r.app, now - r.enqueue_t)
         self.stats.frames_out += len(handle.reqs)
         self._retire(len(handle.reqs))
 
