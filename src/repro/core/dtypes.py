@@ -209,6 +209,29 @@ def mask_to_width(x: np.ndarray, t: DType) -> np.ndarray:
     return x
 
 
+def device_carrier(t: DType):
+    """The dtype a value of scalar type ``t`` leaves the device in: the
+    32-bit integer of its signedness where the declared width fits in 32
+    bits (uint32 for Uint and Bits, int32 for Int), or None where the
+    value keeps the carrier it was computed in (floats, bools, widths above
+    32 bits).  32 bits is a floor: on a TPU v5e an 8-bit readback of the
+    same values served fewer frames a second than a 32-bit one."""
+    if not isinstance(t, (UInt, Int, Bits)) or t.bits() > 32:
+        return None
+    return np.int32 if isinstance(t, Int) else np.uint32
+
+
+def host_carrier(a, copy: bool = True) -> np.ndarray:
+    """One result leaf on the host, on the executor's carrier: integer
+    leaves (sent from the device on ``device_carrier``) widen to int64;
+    other leaves keep their dtype.  ``copy=False`` skips the copy where
+    the leaf is already on its carrier."""
+    a = np.asarray(a)
+    if a.dtype.kind in "iu":
+        return a.astype(np.int64, copy=copy)
+    return a.copy() if copy else a
+
+
 def widen(t: DType, extra_bits: int) -> DType:
     """AddMSBs: widen an integer type (paper fig. 1)."""
     if isinstance(t, UInt):

@@ -59,7 +59,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from ..dtypes import ArrayT, Float, SparseT, TupleT
+from ..dtypes import (ArrayT, Float, SparseT, TupleT, device_carrier,
+                      host_carrier)
 from ..hwimg import Val
 from .ir import IRNode, LoweringIR
 from .lowerers import LOWERERS, jnp_mask
@@ -73,6 +74,44 @@ def _to_numpy(r):
     if isinstance(r, tuple):
         return tuple(_to_numpy(x) for x in r)
     return np.asarray(r)
+
+
+def _to_host(r):
+    """A pipeline result on the host, integer leaves back on the int64
+    carrier (``host_carrier``)."""
+    if isinstance(r, tuple):
+        return tuple(_to_host(x) for x in r)
+    return host_carrier(r, copy=False)
+
+
+def _out_carriers(ty):
+    """How a value of type ``ty`` leaves the device, leaf by leaf (the
+    recursion mirrors ``jnp_mask``): a tuple per tuple level, and at each
+    leaf its declared scalar type where ``device_carrier`` narrows it,
+    else None."""
+    if isinstance(ty, TupleT):
+        return tuple(_out_carriers(t) for t in ty.elems)
+    if isinstance(ty, ArrayT) and isinstance(ty.elem, TupleT):
+        return tuple(_out_carriers(t) for t in ty.elem.elems)
+    while isinstance(ty, ArrayT):
+        ty = ty.elem
+    return ty if device_carrier(ty) is not None else None
+
+
+def _narrowed(carriers):
+    """The narrowed leaves' declared types, in leaf order."""
+    if isinstance(carriers, tuple):
+        return [s for c in carriers for s in _narrowed(c)]
+    return [] if carriers is None else [carriers]
+
+
+def _to_device_carrier(r, carriers):
+    """Cast each narrowed leaf of ``r`` to its ``device_carrier``.
+    Lossless: the value is already wrapped to its declared width
+    (``jnp_mask``), which that dtype holds."""
+    if isinstance(carriers, tuple):
+        return tuple(_to_device_carrier(x, c) for x, c in zip(r, carriers))
+    return r if carriers is None else r.astype(device_carrier(carriers))
 
 
 def _spec(v) -> Any:
@@ -191,9 +230,16 @@ class _Task:
         # the planner's liveness pass): the donate-able batched call path
         # hands these buffers back to XLA for reuse
         self.dead_in: Tuple[int, ...] = ()
+        # per out uid, how its value leaves the program (_out_carriers);
+        # only the pipeline root's narrows (set by the planner)
+        self.out_carriers: Tuple[Any, ...] = (None,) * len(out_uids)
         self._jit: Dict[str, Any] = {}
 
     def _fn(self, *invals):
+        return tuple(_to_device_carrier(r, c) for r, c in
+                     zip(self._outs(*invals), self.out_carriers))
+
+    def _outs(self, *invals):
         env = dict(zip(self.in_uids, invals))
         for n in self.nodes:
             env[n.uid] = _eval_node(n, env)
@@ -231,7 +277,7 @@ class _MKTask(_Task):
         super().__init__(nodes, in_uids, out_uids)
         self.mk = mk
 
-    def _fn(self, *invals):
+    def _outs(self, *invals):
         return self.mk.apply(*invals)
 
 
@@ -270,6 +316,13 @@ class CompiledPipeline:
             self.ir, rules, backend)
         self._inputs = [n for n in self.ir.order if n.op == "Input"]
         self._plan = self._partition()
+        # the root leaves the device on its device_carrier; the host
+        # widens it back to the int64 carrier (_to_host, split_frames)
+        root_ty = self.ir.nodes[self.ir.root].ty
+        carriers = _out_carriers(root_ty)
+        for t in self._plan:
+            t.out_carriers = tuple(carriers if u == self.ir.root else None
+                                   for u in t.out_uids)
         self.notes.append(
             f"lowering backend={backend}: {len(self.fusions)} fused "
             f"dispatch(es), {self.graph_rewrites} graph rewrite(s); "
@@ -280,6 +333,11 @@ class CompiledPipeline:
                   if self.megakernels else "")))
         for mk in self.megakernels:
             self.notes.append("  " + mk.report_line())
+        if not debug:
+            for s in _narrowed(carriers):
+                self.notes.append(
+                    f"output carrier: {np.dtype(device_carrier(s)).name} "
+                    f"for {s!r} (was int64)")
         # per-signature call counts; the first call at a signature traces
         # and XLA-compiles, later calls hit the jit cache
         self.signatures: Dict[Tuple[str, Any], int] = {}
@@ -478,7 +536,7 @@ class CompiledPipeline:
             if self.debug:
                 return _to_numpy(self._eval(inputs))
             self._record(inputs, "frame")
-            return _to_numpy(self._run(inputs, "frame"))
+            return _to_host(self._run(inputs, "frame"))
 
     def run_batch(self, inputs: Dict[str, Any]):
         """vmap over a leading frame axis on every input (the throughput /
@@ -487,7 +545,7 @@ class CompiledPipeline:
             if self.debug:
                 return _to_numpy(jax.vmap(self._eval)(inputs))
             self._record(inputs, "batch")
-            return _to_numpy(self._run(inputs, "batch"))
+            return _to_host(self._run(inputs, "batch"))
 
     def run_batch_device(self, inputs: Dict[str, Any], donate: bool = False,
                          mesh=None):
@@ -500,7 +558,10 @@ class CompiledPipeline:
         reused for outputs; a no-op where the platform lacks donation).
         Callers must not reuse donated input arrays afterwards.  ``mesh``
         (a 1-d mesh over the ``frames`` axis, serve/sharding.py) runs every
-        program once per device on that device's shard of the frames."""
+        program once per device on that device's shard of the frames.
+        Integer leaves of the result are on ``device_carrier`` (32 bits
+        where the declared type fits), not on the int64 carrier:
+        ``split_frames`` widens them back on the host."""
         with jax.enable_x64(True):
             self._record(inputs, "serve")
             return self._run(inputs, "batch", donate=donate, mesh=mesh)

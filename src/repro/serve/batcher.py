@@ -33,6 +33,8 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from repro.core.dtypes import host_carrier
+
 
 def frame_signature(inputs: Dict[str, Any]) -> Tuple:
     """Hashable (name, shape, dtype) signature of one frame's input dict
@@ -83,12 +85,13 @@ def split_frames(out: Any, n: int) -> List[Any]:
     """Invert ``stack_frames`` on a batched output (array or tuple of
     arrays), dropping padding rows beyond ``n``.  Frames are copied out of
     the batch buffer: a client retaining one frame's result must not pin
-    the whole (padded) batch in memory."""
+    the whole (padded) batch in memory.  The copy widens integer leaves,
+    which the device sends on a narrower carrier, back to int64."""
     if isinstance(out, tuple):
         per = [split_frames(e, n) for e in out]
         return [tuple(p[i] for p in per) for i in range(n)]
     a = np.asarray(out)
-    return [a[i].copy() for i in range(n)]
+    return [host_carrier(a[i]) for i in range(n)]
 
 
 def next_pow2(n: int) -> int:

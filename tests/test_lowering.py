@@ -439,3 +439,49 @@ def test_structural_ops_cross_backend(backend):
     lp = lower_pipeline(out, backend=backend)
     x = rng.randint(0, 256, (12, 16)).astype(np.int64)
     assert _eq(evaluate(out, {"x": x}), lp({"x": x}))
+
+
+# ---- declared-width output carrier ----
+
+@pytest.mark.parametrize("backend", BACKENDS)
+@pytest.mark.parametrize("ty,device", [
+    (UInt(1), "uint32"), (UInt(8), "uint32"), (UInt(9), "uint32"),
+    (UInt(16), "uint32"), (UInt(17), "uint32"), (UInt(32), "uint32"),
+    (UInt(33), "int64"), (Int(12), "int32")])
+def test_output_carrier_type_edges(ty, device, backend):
+    """A point-op root of at most 32 bits leaves the device on the 32-bit
+    integer of its signedness (int64 above 32 bits) and comes back to
+    every caller as the executor's int64, sign included."""
+    from repro.serve import split_frames
+    rng = np.random.RandomState(ty.bits())
+    x, y = Input(Array2d(ty, 9, 6), "x"), Input(Array2d(ty, 9, 6), "y")
+    out = Map(Min)(x, y)
+    lo, hi = ((-(1 << (ty.bits() - 1)), 1 << (ty.bits() - 1))
+              if isinstance(ty, Int) else (0, 1 << ty.bits()))
+    xb = rng.randint(lo, hi, (3, 6, 9), dtype=np.int64)
+    yb = rng.randint(lo, hi, (3, 6, 9), dtype=np.int64)
+    xb[:, 0, 0] = yb[:, 0, 0] = hi - 1          # both ends of the range
+    xb[:, 0, 1] = lo
+    lp = lower_pipeline(out, backend=backend)
+    dev = lp.run_batch_device({"x": xb, "y": yb})
+    assert str(dev.dtype) == device
+    refs = [evaluate(out, {"x": xb[i], "y": yb[i]}) for i in range(3)]
+    got = lp.run_batch({"x": xb, "y": yb})
+    assert got.dtype == np.int64 and _eq(np.stack(refs), got)
+    for i, f in enumerate(split_frames(dev, 3)):
+        assert f.dtype == np.int64 and _eq(refs[i], f)
+        one = lp({"x": xb[i], "y": yb[i]})
+        assert one.dtype == np.int64 and _eq(refs[i], one)
+    if isinstance(ty, Int):
+        assert got.min() < 0 and got.min() == np.stack(refs).min()
+
+
+def test_lowering_report_names_output_carrier(lowering_cases):
+    """The report names the root's device dtype where it narrows (STEREO's
+    disparity) and says nothing for a float root (FLOW)."""
+    stereo, _ = lowering_cases["stereo"]
+    lp = stereo.lower("pallas")
+    assert "output carrier: uint32 for Uint(3) (was int64)" in lp.notes
+    assert "output carrier: uint32" in stereo.lowering_report()
+    flow, _ = lowering_cases["flow"]
+    assert not any("output carrier" in n for n in flow.lower("pallas").notes)

@@ -152,6 +152,74 @@ def test_engine_exposes_frame_signature(lowering_cases):
     assert isinstance(hash(a), int)
 
 
+# ---- declared-width readback ----
+
+# the leaves run_batch_device returns: integer roots of at most 32 bits
+# on the 32-bit integer of their signedness, float roots untouched
+DEVICE_DTYPES = {"convolution": ["uint32"], "stereo": ["uint32"],
+                 "flow": ["float32", "float32"],
+                 "descriptor": ["float32", "uint32"], "pyramid": ["uint32"]}
+
+
+def _leaves(v):
+    return list(v) if isinstance(v, tuple) else [v]
+
+
+def _assert_executor_identical(got, want):
+    """Same leaves, same dtypes (int64 for integers), same values."""
+    got, want = _leaves(got), _leaves(want)
+    assert len(got) == len(want)
+    for g, w in zip(got, want):
+        assert isinstance(g, np.ndarray) and g.dtype == np.asarray(w).dtype
+        assert np.array_equal(g, w)
+
+
+@pytest.mark.parametrize("backend", ["jax", "pallas"])
+@pytest.mark.parametrize("app", sorted(DEVICE_DTYPES))
+def test_run_batch_device_leaves_on_device_carrier(app, backend,
+                                                   lowering_cases):
+    """Also through megakernel roots (FLOW, PYRAMID on pallas)."""
+    import jax
+    design, inputs_fn = lowering_cases[app]
+    batch = inputs_fn(np.random.RandomState(4), frames=2)
+    out = design.lower(backend).run_batch_device(batch)
+    assert [str(x.dtype) for x in jax.tree_util.tree_leaves(out)] \
+        == DEVICE_DTYPES[app]
+
+
+@pytest.mark.parametrize("app", sorted(DEVICE_DTYPES))
+def test_results_return_on_int64_carrier(app, lowering_cases):
+    """split_frames, run and run_batch widen the narrow device leaves back:
+    every caller sees the executor's dtypes and values."""
+    design, inputs_fn = lowering_cases[app]
+    batch = inputs_fn(np.random.RandomState(4), frames=2)
+    ref = design.run_batch(batch, backend="numpy")
+    lp = design.lower("jax")
+    _assert_executor_identical(lp.run_batch(batch), ref)
+    for i, got in enumerate(split_frames(lp.run_batch_device(batch), 2)):
+        one = {k: tuple(e[i] for e in v) if isinstance(v, tuple) else v[i]
+               for k, v in batch.items()}
+        want = evaluate(design.out_val, one)
+        _assert_executor_identical(got, want)
+        _assert_executor_identical(design.run(one, backend="jax"), want)
+
+
+def test_server_answers_stay_int64():
+    """FrameServer answers of a narrowed integer root are int64 and equal
+    to the executor's."""
+    from repro.apps import BENCH_CASES
+    from repro.core import compile_pipeline
+    uf, inputs_fn = BENCH_CASES["stereo"]()
+    design = compile_pipeline(uf)
+    frames = [inputs_fn(np.random.RandomState(20 + i)) for i in range(3)]
+    with FrameServer(ServeConfig(max_batch=2, max_delay_ms=5.0)) as srv:
+        srv.register(design, name="stereo")
+        outs = [f.result(timeout=300)
+                for f in [srv.submit(x, app="stereo") for x in frames]]
+    for inp, out in zip(frames, outs):
+        _assert_executor_identical(out, evaluate(design.out_val, inp))
+
+
 # ---- live server round trips ----
 
 def test_server_round_trip_bit_exact_two_apps(lowering_cases):

@@ -143,9 +143,12 @@ def test_traced_serve_records_every_frame_and_batch(tmp_path):
     d2h = spans.records("serve.d2h")
     assert sum(r.ids["n"] for r in d2h) == n_frames
     assert len(d2h) == srv.stats.batches
-    # padding rows are read back too: every row the batches carried
+    # padding rows are read back too: every row the batches carried, on
+    # the root's 32-bit device carrier (Uint(8): four bytes a pixel), while
+    # the answers are back on the int64 carrier
     rows = srv.stats.batch_frames + srv.stats.padded_frames
-    assert spans.counters()["serve.d2h_bytes"] == rows * outs[0].nbytes
+    assert outs[0].dtype == np.int64
+    assert spans.counters()["serve.d2h_bytes"] == rows * outs[0].size * 4
     assert not any(r.name.startswith("bench.") for r in spans.records())
 
 
