@@ -36,10 +36,11 @@ def main(argv=None) -> int:
     for seed in args.seeds:
         t0 = time.perf_counter()
         n = traffic.pool_size(cell.mix, cell.chips)
-        pairs = traffic.make_pool(cell.mix, pipe["h"], pipe["w"], n, seed)
+        pool = traffic.make_pool(cell.mix, pipe["h"], pipe["w"], n, seed,
+                                 cell.images)
         pick = traffic.order(n, k, seed)
-        got = [(cell.ref.control(*pairs[p], **pipe),
-                cell.ref.reference(*pairs[p], **pipe)) for p in pick]
+        got = [(cell.ref.control(*pool[p], **pipe),
+                cell.ref.reference(*pool[p], **pipe)) for p in pick]
         ok, checks = compare.verdict(compare.readings(got, 0),
                                      cell.cfg["limits"])
         worst = worst and not ok

@@ -46,8 +46,9 @@ def main(argv=None) -> int:
     design = compile_pipeline(PIPELINES[cfg["app"]](**pipe),
                               options=CompileOptions(backend="pallas"))
     pool = traffic.make_pool(cell.mix, pipe["h"], pipe["w"],
-                             int(cell.mix["pool_frames"]), args.seed)
-    frames = [{cfg["input"]: p} for p in pool]
+                             int(cell.mix["pool_frames"]), args.seed,
+                             cell.images)
+    frames = traffic.server_inputs(cfg["input"], pool)
     srv = FrameServer(ServeConfig(devices=list(devices)))
     srv.register(design, backend="pallas", warm_inputs=[frames[0]])
     srv.start()

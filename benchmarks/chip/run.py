@@ -79,11 +79,7 @@ class Cell:
         self.spec = cells[name]
         self.chips = int(self.spec["chips"])
         entry = {c["name"]: c for c in bench["configs"]}[self.spec["config"]]
-        cfg_path = os.path.join(root, entry["file"])
-        with open(cfg_path) as f:
-            self.cfg = json.load(f)
-        self.ref = load_module(os.path.join(os.path.dirname(cfg_path),
-                                            self.cfg["reference"]))
+        self.load_config(os.path.join(root, entry["file"]))
         self.mix = traffic.load(os.path.join(
             HERE, "traffic", self.spec["traffic"] + ".json"))
 
@@ -93,6 +89,16 @@ class Cell:
         reported = {m["name"] for m in self.end_to_end}
         self.per_layer = [m for m in bench["per_layer"] if mine(m)
                           and m["moves"] in reported]
+
+    def load_config(self, path: str) -> None:
+        """The configuration file at ``path``, the number of uint8 images
+        a frame carries (``images``, which it must state), and the plain
+        reference beside it."""
+        with open(path) as f:
+            self.cfg = json.load(f)
+        self.images = int(self.cfg["images"])
+        self.ref = load_module(os.path.join(os.path.dirname(path),
+                                            self.cfg["reference"]))
 
 
 def check_devices(chips: int):
@@ -177,8 +183,9 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, devices,
                               options=CompileOptions(backend="pallas"))
     t_gen = time.perf_counter()
     n_pool = traffic.pool_size(mix, chips)
-    pairs = traffic.make_pool(mix, pipe["h"], pipe["w"], n_pool, seed)
-    frames = [{cfg["input"]: p} for p in pairs]
+    pool = traffic.make_pool(mix, pipe["h"], pipe["w"], n_pool, seed,
+                             cell.images)
+    frames = traffic.server_inputs(cfg["input"], pool)
     t_gen = time.perf_counter() - t_gen
 
     srv = FrameServer(ServeConfig(devices=list(devices)))
@@ -270,7 +277,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, devices,
     for i, out in sorted(rec.sample, key=lambda s: s[0]):
         p = int(rec.pool_idx[i])
         if p not in refs:
-            refs[p] = cell.ref.reference(*pairs[p], **pipe)
+            refs[p] = cell.ref.reference(*pool[p], **pipe)
         pairs_checked.append((out, refs[p]))
     values = compare.readings(pairs_checked, unanswered)
     if not pairs_checked:

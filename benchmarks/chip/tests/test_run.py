@@ -1,7 +1,9 @@
 """A whole run of the harness on the CPU at a tiny frame size, with the
 look for a chip skipped: clean, it is correct; with the served path
-broken underneath, ``correct`` comes out false.  And the command itself
-refuses to run without a TPU."""
+broken underneath, ``correct`` comes out false.  Two-image configurations
+of the benchmark and a one-image one kept as test data (``data/``) are
+driven alike.  And the command itself refuses to run without a TPU, or
+with a configuration that does not state its images."""
 import json
 import os
 import subprocess
@@ -14,10 +16,21 @@ import pytest
 import run
 
 ROOT = run.ROOT
+DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
 TINY = {
     "flow_1080p": {"w": 64, "h": 32},
     "stereo_720x400": {"w": 128, "h": 16, "nd": 64},
+    "convolution_64x32": {"w": 64, "h": 32},     # one image a frame
 }
+
+
+def config_path(config):
+    """The file of a configuration: the benchmark's, else test data."""
+    for d in (os.path.join(run.HERE, "configs"), DATA):
+        path = os.path.join(d, config + ".json")
+        if os.path.exists(path):
+            return path
+    raise FileNotFoundError(config)
 
 
 def tiny_cell(name, config=None, **mix):
@@ -26,10 +39,7 @@ def tiny_cell(name, config=None, **mix):
     cell = run.Cell(name)
     config = config or cell.spec["config"]
     if config != cell.spec["config"]:
-        path = os.path.join(run.HERE, "configs", config)
-        with open(path + ".json") as f:
-            cell.cfg = json.load(f)
-        cell.ref = run.load_module(path + ".py")
+        cell.load_config(config_path(config))
     cell.cfg = dict(cell.cfg, pipeline=TINY[config], check_frames=4)
     cell.mix = dict(cell.mix, **mix)
     return cell
@@ -92,9 +102,9 @@ class Stale:
 
     def __call__(self, outs):
         prev, self.last = self.last, outs
-        if prev is None or len(prev) < len(outs):
+        if prev is None:
             return outs
-        return prev[:len(outs)]
+        return [prev[i % len(prev)] for i in range(len(outs))]
 
 
 @pytest.mark.parametrize("fault", ["altered", "half_left_out", "stale"])
@@ -106,7 +116,12 @@ def test_broken_serve_path_is_not_correct(config, fault, monkeypatch):
          "stale": Stale()}[fault]
     monkeypatch.setattr(dispatch, "split_frames",
                         lambda out, n: f(orig(out, n)))
-    res = cpu_run(tiny_cell("stereo_720x400.saturated", config, **SATURATED))
+    cell = tiny_cell("stereo_720x400.saturated", config, **SATURATED)
+    # batches on the CPU come in every size from 1 to 8, and a fault leaves
+    # some of their frames right (a batch of one has no half to leave out):
+    # compare enough frames that one wrong answer is all but sure to be seen
+    cell.cfg["check_frames"] = 16
+    res = cpu_run(cell)
     assert not res["correct"], res["checks"]
 
 
@@ -137,9 +152,10 @@ def test_control_is_not_correct(config):
     import traffic
     cell = tiny_cell("stereo_720x400.saturated", config)
     pipe = cell.cfg["pipeline"]
-    pairs = traffic.make_pool(cell.mix, pipe["h"], pipe["w"], 4, 3)
-    refs = [cell.ref.reference(a, b, **pipe) for a, b in pairs]
-    ctl = [cell.ref.control(a, b, **pipe) for a, b in pairs]
+    pool = traffic.make_pool(cell.mix, pipe["h"], pipe["w"], 4, 3,
+                             cell.images)
+    refs = [cell.ref.reference(*p, **pipe) for p in pool]
+    ctl = [cell.ref.control(*p, **pipe) for p in pool]
     ok, _ = compare.verdict(compare.readings(zip(refs, refs), 0),
                             cell.cfg["limits"])
     assert ok
@@ -180,35 +196,71 @@ def test_command_refuses_without_the_program(tmp_path):
     assert p.stdout.strip() == ""
 
 
-@pytest.mark.parametrize("name", ["stereo_720x400.saturated",
-                                  "flow_1080p.cameras"])
-def test_traced_run_reports_the_cells_per_layer_metrics(name, monkeypatch):
+def bench():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        return json.load(f)
+
+
+def listed_per_layer(bench, name):
+    """The per-layer metrics BENCHMARK.json gives cell ``name``: those
+    that list it (or list no cells) and move an end-to-end metric that
+    the cell reports."""
+    def lists(m):
+        return name in m.get("workloads", [name])
+    reported = {m["name"] for m in bench["end_to_end"] if lists(m)}
+    return {m["name"] for m in bench["per_layer"]
+            if lists(m) and m["moves"] in reported}
+
+
+@pytest.mark.parametrize("name", [w["name"] for w in bench()["workloads"]])
+def test_traced_run_reports_the_cells_per_layer_metrics(name, monkeypatch,
+                                                        tmp_path):
     """The traced path end to end, with the chip's trace taken from the
-    recorded one (a CPU trace has no TPU plane)."""
+    recorded one (a CPU trace has no TPU plane): every per-layer metric
+    that BENCHMARK.json lists for the cell is read, and no other."""
     import devtrace
-    data = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data",
-                        "flow_256x32.xplane.pb")
+    monkeypatch.setattr(run, "TRACE_DIR", str(tmp_path / "trace"))
+    data = os.path.join(DATA, "flow_256x32.xplane.pb")
     monkeypatch.setattr(devtrace, "reduce_dir",
                         lambda d, span: devtrace.reduce_file(data, span))
-    mix = SATURATED if name.endswith("saturated") else dict(cameras=2,
-                                                           pool_frames=8)
+    closed = run.Cell(name).mix["loop"] == "closed"
+    mix = SATURATED if closed else dict(cameras=2, pool_frames=8)
     cell = tiny_cell(name, "flow_1080p", **mix)
     import work
     monkeypatch.setattr(work, "peaks", lambda kind: {"ops_per_s": 197e12,
                                                      "hbm_bytes_per_s": 819e9})
     res = cpu_run(cell, trace=True)
     assert res["correct"], res["checks"]
-    assert set(res["metrics"]) == {m["name"] for m in cell.per_layer}
-    want = {"stereo_720x400.saturated": {
-        "device_idle_pct.saturated", "pipeline_roofline_pct.saturated",
-        "xla_ms_per_frame.saturated", "warmup_s"},
-        "flow_1080p.cameras": {"device_idle_pct.cameras",
-                               "batch_frames_mean.cameras", "warmup_s"}}
-    assert set(res["metrics"]) == want[name]
+    want = listed_per_layer(bench(), name)
+    assert want and set(res["metrics"]) == want
     for m in res["metrics"].values():
         assert m["value"] >= 0
     assert 0 < res["device"]["busy_s"] < res["device"]["window_s"]
     assert set(res["breakdown"]) == {"device_ops", "idle_gaps"}
+
+
+def test_configuration_without_images_is_refused(tmp_path):
+    """A configuration file that does not state how many images a frame
+    carries is refused before any run: non-zero exit, no result."""
+    import shutil
+    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), tmp_path)
+    shutil.copytree(os.path.join(ROOT, "benchmarks", "chip"),
+                    tmp_path / "benchmarks" / "chip",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    path = tmp_path / "benchmarks" / "chip" / "configs" / "stereo_720x400.json"
+    cfg = json.loads(path.read_text())
+    del cfg["images"]
+    path.write_text(json.dumps(cfg))
+    with pytest.raises(KeyError, match="images"):
+        run.Cell("stereo_720x400.saturated", root=str(tmp_path))
+    p = subprocess.run(
+        [sys.executable, os.path.join("benchmarks", "chip", "run.py"),
+         "--workload", "stereo_720x400.saturated", "--seed", "1",
+         "--seconds", "1", "--trace", "0"],
+        cwd=tmp_path, capture_output=True, text=True, timeout=300)
+    assert p.returncode != 0
+    assert "refused" in p.stderr and "images" in p.stderr
+    assert p.stdout.strip() == ""
 
 
 FOUR_DEVICES = r'''

@@ -6,6 +6,7 @@ import threading
 import time
 
 import numpy as np
+import pytest
 
 import traffic
 import repro.serve  # noqa: F401  (the generator catches its Overloaded)
@@ -21,9 +22,9 @@ def mix(name):
 
 def test_pool_is_a_function_of_the_seed():
     m = mix("saturated")
-    a = traffic.make_pool(m, 6, 10, 5, BIG_SEED)
-    b = traffic.make_pool(m, 6, 10, 5, BIG_SEED)
-    c = traffic.make_pool(m, 6, 10, 5, BIG_SEED + 1)
+    a = traffic.make_pool(m, 6, 10, 5, BIG_SEED, 2)
+    b = traffic.make_pool(m, 6, 10, 5, BIG_SEED, 2)
+    c = traffic.make_pool(m, 6, 10, 5, BIG_SEED + 1, 2)
     for (x1, y1), (x2, y2) in zip(a, b):
         assert x1.dtype == np.uint8 and y1.dtype == np.uint8
         assert np.array_equal(x1, x2) and np.array_equal(y1, y2)
@@ -39,8 +40,49 @@ def test_pool_frames_are_distinct():
     m = mix("saturated")
     n = traffic.pool_size(m, 1)
     assert n >= 2 * traffic.outstanding(m, 1)
-    pool = traffic.make_pool(m, 8, 16, n, 3)
+    pool = traffic.make_pool(m, 8, 16, n, 3, 2)
     assert len({x.tobytes() for x, _ in pool}) == n
+
+
+def pinned_pairs(mix, h, w, n, seed):
+    """The two-image pool as the benchmark first drew it: the (n, h, w)
+    block of first images, then the shifts, from the pool's stream."""
+    g = np.random.default_rng([int(seed) % 2 ** 64, 0])
+    lo, hi = mix["shift_px"]
+    first = g.integers(0, 256, (n, h, w), dtype=np.uint8)
+    shifts = g.integers(lo, hi + 1, n)
+    return [(first[i], np.roll(first[i], int(shifts[i]), axis=1))
+            for i in range(n)]
+
+
+@pytest.mark.parametrize("seed", [3, 2 ** 31 - 1, BIG_SEED])
+@pytest.mark.parametrize("name", ["saturated", "cameras"])
+def test_two_image_pool_is_unchanged(name, seed):
+    """Two-image cells keep the pool they were measured on, byte for byte,
+    and ``server_inputs`` hands the server the same pairs."""
+    m = mix(name)
+    got = traffic.make_pool(m, 8, 12, 9, seed, 2)
+    want = pinned_pairs(m, 8, 12, 9, seed)
+    assert len(got) == len(want)
+    for g, w in zip(got, want):
+        assert len(g) == 2
+        for a, b in zip(g, w):
+            assert a.dtype == np.uint8 and a.tobytes() == b.tobytes()
+    inputs = traffic.server_inputs("app.in", got)
+    assert all(d["app.in"] is p for d, p in zip(inputs, got))
+
+
+@pytest.mark.parametrize("seed", [5, BIG_SEED])
+def test_one_image_pool_is_the_first_images(seed):
+    m = mix("saturated")
+    one = traffic.make_pool(m, 8, 12, 9, seed, 1)
+    two = traffic.make_pool(m, 8, 12, 9, seed, 2)
+    assert [len(p) for p in one] == [1] * 9
+    assert all(np.array_equal(a[0], b[0]) for a, b in zip(one, two))
+    inputs = traffic.server_inputs("app.in", one)
+    assert all(d["app.in"] is p[0] for d, p in zip(inputs, one))
+    with pytest.raises(ValueError):
+        traffic.make_pool(m, 8, 12, 9, seed, 3)
 
 
 def test_order_cycles_through_the_pool():

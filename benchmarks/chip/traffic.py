@@ -13,10 +13,12 @@ key picks the discipline and the other keys are its parameters:
   not earlier frames are done.  Frames come from a pool of ``pool_frames``
   distinct frames, in a seeded order.
 
-Every frame pair is made from the seed: the first image is uniform uint8
-noise, the second the first shifted right by a seeded whole number of
-pixels in ``shift_px`` (inclusive).  The same seed gives the same pool,
-order and schedule.
+Every frame is made from the seed and carries as many uint8 images as
+the configuration's ``images`` states: the first image is uniform uint8
+noise; a second, where there is one, is the first shifted right by a
+seeded whole number of pixels in ``shift_px`` (inclusive).  The same seed
+gives the same pool, order and schedule, and a one-image pool holds the
+first images of the two-image pool of that seed.
 """
 from __future__ import annotations
 
@@ -53,14 +55,25 @@ def pool_size(mix: dict, chips: int) -> int:
     return int(mix["pool_frames"])
 
 
-def make_pool(mix: dict, h: int, w: int, n: int, seed: int):
-    """``n`` distinct (first, second) uint8 frame pairs of ``h`` x ``w``."""
+def make_pool(mix: dict, h: int, w: int, n: int, seed: int, images: int):
+    """``n`` distinct frames of ``h`` x ``w``: tuples of ``images`` uint8
+    images, ``(first,)`` or ``(first, second)``."""
     g = rng(seed, POOL)
-    lo, hi = mix["shift_px"]
     first = g.integers(0, 256, (n, h, w), dtype=np.uint8)
+    if images == 1:
+        return [(first[i],) for i in range(n)]
+    if images != 2:
+        raise ValueError(f"a frame carries 1 or 2 images, not {images!r}")
+    lo, hi = mix["shift_px"]
     shifts = g.integers(lo, hi + 1, n)
     return [(first[i], np.roll(first[i], int(shifts[i]), axis=1))
             for i in range(n)]
+
+
+def server_inputs(name: str, pool) -> list:
+    """The server's input dict of each frame of ``pool``: a one-image
+    pipeline takes the bare image, a two-image one the tuple."""
+    return [{name: p[0] if len(p) == 1 else p} for p in pool]
 
 
 def order(n_pool: int, n: int, seed: int) -> np.ndarray:
