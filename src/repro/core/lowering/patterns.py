@@ -179,11 +179,18 @@ def _sad_guard(m: Match) -> bool:
         return False
     if left.shape != right.shape:
         return False
-    # exactness guard: the SAD sum must not wrap (executor width or int32)
+    # the kernel's limits: 8-bit images (|L - R| is exact in bfloat16 on
+    # the MXU) and blocks at most one halo block taller than a row
+    from repro.kernels.sad.kernel import HALO
+    bits = max(left.scalar.bits(), right.scalar.bits())
     bh, bw = _stencil_size(m["patch"].params)
+    if bits > 8 or bh - 1 > HALO:
+        return False
+    # exactness guard: the SAD sum must not wrap (executor width), and the
+    # kernel's float32 costs are exact below 2^24
     acc_bits = m["acc"].scalar.bits()
-    max_sum = (2 ** max(left.scalar.bits(), right.scalar.bits()) - 1) * bh * bw
-    return max_sum < 2 ** min(acc_bits, 31)
+    max_sum = (2 ** bits - 1) * bh * bw
+    return max_sum < 2 ** min(acc_bits, 24)
 
 
 def _sad_build(m: Match) -> Dispatch:

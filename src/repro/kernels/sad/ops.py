@@ -4,18 +4,17 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 from ..stream import interpret_default
-from .kernel import TILE_ROWS, sad_strips
+from .kernel import plane_rows, sad_strips
 
 
 def sad_disparity(l, r, *, nd: int = 64, bh: int = 8, bw: int = 8):
-    """Best-match disparity per pixel (see ref.py contract)."""
+    """Best-match disparity per pixel (see ref.py contract) of 8-bit
+    images: values in [0, 256), blocks at most 9 rows high."""
     l = jnp.asarray(l, jnp.int32)
     r = jnp.asarray(r, jnp.int32)
     h = l.shape[0] - bh + 1
     w = l.shape[1] - bw + 1 - (nd - 1)
-    h_pad = (-h) % TILE_ROWS
-    rows_needed = h + h_pad + TILE_ROWS
-    extra = rows_needed - l.shape[0]
+    extra = plane_rows(h, l.shape[1]) - l.shape[0]
     if extra > 0:
         l = jnp.pad(l, ((0, extra), (0, 0)))
         r = jnp.pad(r, ((0, extra), (0, 0)))

@@ -74,15 +74,40 @@ def test_conv2d_kernel_vs_ref(h, w, kh, kw, shift):
     assert np.array_equal(out, ref)
 
 
-@pytest.mark.parametrize("h,w,nd,bh,bw", [
-    (8, 24, 16, 8, 8), (12, 40, 4, 4, 4),
+def _sad_planes(kind, shape):
+    """Random planes, or planes on which several disparities tie: constant
+    (every disparity costs the same) or equal and periodic along the lanes
+    with period 4 (cost 0 every 4 disparities)."""
+    if kind == "random":
+        return (rng.randint(0, 256, shape).astype(np.int32),
+                rng.randint(0, 256, shape).astype(np.int32))
+    if kind == "constant":
+        return np.full(shape, 7, np.int32), np.full(shape, 200, np.int32)
+    period = rng.randint(0, 256, (shape[0], 4)).astype(np.int32)
+    plane = np.tile(period, (1, -(-shape[1] // 4)))[:, :shape[1]]
+    return plane, plane.copy()
+
+
+@pytest.mark.parametrize("h,w,nd,bh,bw,kind", [
+    pytest.param(8, 24, 16, 8, 8, "random", id="8-24-16-8-8"),
+    pytest.param(12, 40, 4, 4, 4, "random", id="12-40-4-4-4"),
+    # several row tiles, and a height that no tile divides
+    (45, 16, 8, 8, 8, "random"),
+    # wider than one 128-lane register
+    (8, 150, 16, 8, 8, "random"),
+    # ties: the first least disparity wins
+    (8, 24, 16, 8, 8, "constant"), (9, 20, 14, 8, 8, "periodic"),
+    # the paper's 8x8 blocks over 64 disparities, at a small height
+    (8, 16, 64, 8, 8, "random"),
 ])
-def test_sad_kernel_vs_ref(h, w, nd, bh, bw):
-    L = rng.randint(0, 256, (h + bh - 1, w + bw - 1 + nd - 1)).astype(np.int32)
-    R = rng.randint(0, 256, (h + bh - 1, w + bw - 1 + nd - 1)).astype(np.int32)
+def test_sad_kernel_vs_ref(h, w, nd, bh, bw, kind):
+    L, R = _sad_planes(kind, (h + bh - 1, w + bw - 1 + nd - 1))
     out = sad_disparity(L, R, nd=nd, bh=bh, bw=bw)
     ref = sad_ref(jnp.asarray(L), jnp.asarray(R), nd=nd, bh=bh, bw=bw)
     assert np.array_equal(out, ref)
+    if kind != "random":      # disparity 0 aligns column j + nd - 1 with j
+        first = 0 if kind == "constant" else (nd - 1) % 4
+        assert np.all(np.asarray(out) == first)
 
 
 @pytest.mark.parametrize("B,S,H,Hkv,D,window,dtype,atol", [

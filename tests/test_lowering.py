@@ -134,6 +134,26 @@ def test_sad_wrap_guard_boundary():
         assert _eq(evaluate(out, {"p": (l, r)}), lp({"p": (l, r)}))
 
 
+@pytest.mark.parametrize("bits,bh", [(9, 8), (8, 10)])
+def test_sad_fusion_needs_8_bit_images_and_short_blocks(bits, bh):
+    """Images wider than 8 bits (|L - R| not exact in bfloat16) or blocks
+    taller than one halo block below a row fall back, bit-exact."""
+    from repro.core import ArgMin, ReducePatch, Replicate, TupleT
+    rng = np.random.RandomState(11)
+    img = Array2d(UInt(bits), 24, 20)
+    inp = Input(TupleT((img, img)), "p")
+    left, right = inp[0], inp[1]
+    cand = Stencil(-3, 0, 0, 0)(right)
+    diff = Map(AbsDiff)(Replicate(4, 1)(left), cand)
+    patches = Stencil(-3, 0, -(bh - 1), 0)(Map(AddMSBs(12))(diff))
+    out = ArgMin(ReducePatch(AddAsync)(patches))
+    lp = lower_pipeline(out, backend="pallas")
+    assert not lp.fusions, lp.notes
+    l = rng.randint(0, 2 ** bits, (20, 24)).astype(np.int64)
+    r = np.roll(l, 2, axis=-1)
+    assert _eq(evaluate(out, {"p": (l, r)}), lp({"p": (l, r)}))
+
+
 def test_multi_consumer_stencil_is_not_fused():
     """A stencil whose patches feed a second consumer must not be claimed
     by the conv2d fusion (interior single-consumer discipline)."""

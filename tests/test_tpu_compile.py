@@ -68,14 +68,19 @@ def test_conv2d_compiles_at_1080p(chip_compile):
     assert c.memory_analysis().output_size_in_bytes == 1080 * 1920 * 4
 
 
-def test_sad_compiles_at_720x400_64_disparities(chip_compile):
-    from repro.kernels.sad.kernel import TILE_ROWS, sad_strips
-    shape = (400 + TILE_ROWS, 720 + 7 + 63)
+@pytest.mark.parametrize("h,w", [(400, 720), (375, 1242)])
+def test_sad_compiles_at_720x400_64_disparities(h, w, chip_compile):
+    """STEREO's frame, and the KITTI frame (a height that is not a
+    multiple of 8), in planes shaped as ``sad_disparity`` pads them."""
+    from repro.kernels.sad.kernel import plane_rows, sad_strips
+    w_in = w + 7 + 63
+    shape = (plane_rows(h, w_in), w_in)
     s = jax.ShapeDtypeStruct(shape, jnp.int32, sharding=chip_compile)
     c = jax.jit(lambda l, r: sad_strips(
-        l, r, nd=64, bh=8, bw=8, w_out=720, interpret=False)).lower(
+        l, r, nd=64, bh=8, bw=8, w_out=w, interpret=False)).lower(
             s, s).compile()
     assert "tpu_custom_call" in c.as_text()
+    assert c.memory_analysis().output_size_in_bytes >= h * w * 4
 
 
 @pytest.mark.parametrize("app", ["flow", "pyramid", "descriptor"])
